@@ -40,8 +40,7 @@ def main():
     # the paper's step 1: K x K Pearson matrix, streamed per leaf through
     # the Pallas kernel (bf16 read, f32 accumulate — one HBM pass)
     corr = np.asarray(
-        pearson_tree(stacked, compute_dtype=jnp.bfloat16,
-                     use_kernel=True, interpret=True)
+        pearson_tree(stacked, compute_dtype=jnp.bfloat16, use_kernel=True)
     )
     print("correlation matrix:\n", corr.round(3))
 

@@ -191,7 +191,8 @@ class FLConfig:
     @property
     def pearson_interpret(self) -> bool:
         """Pallas interpret mode: only off on a real accelerator."""
-        return jax.default_backend() == "cpu"
+        from repro.kernels.platform import resolve_interpret
+        return resolve_interpret()
 
     @property
     def local_steps(self) -> int:
@@ -288,7 +289,7 @@ class FederatedSimulator:
             for x, y in self.scenario.apply_data_attacks(client_shards, fl.seed)
         ]
         self.K = len(self.shards)
-        self.policy = make_merge_policy(fl)
+        self.policy = make_merge_policy(fl, mesh)
         self.rng = np.random.default_rng(fl.seed)
 
         key = jax.random.PRNGKey(fl.seed)

@@ -53,6 +53,10 @@ class MergePolicy:
     """Base policy: similarity is abstract, planning is the paper's greedy
     threshold grouping over whatever similarity the subclass computes."""
 
+    # the simulator's mesh (None when unmeshed): kernel-backed similarity
+    # programs run inside it (see core/pearson.pearson_tree)
+    mesh = None
+
     def __init__(self, fl):
         self.fl = fl
 
@@ -113,6 +117,7 @@ class PearsonPolicy(MergePolicy):
             seed=self.fl.seed,
             use_kernel=self.fl.pearson_kernel,
             interpret=self.fl.pearson_interpret,
+            mesh=self.mesh,
         )
 
     def _host_similarity(self, x_locals) -> np.ndarray:
@@ -245,5 +250,7 @@ def _stacked_k(x_locals) -> int:
     return jax.tree_util.tree_leaves(x_locals)[0].shape[0]
 
 
-def make_merge_policy(fl) -> MergePolicy:
-    return MERGE_POLICIES.get(fl.merge_policy)(fl)
+def make_merge_policy(fl, mesh=None) -> MergePolicy:
+    policy = MERGE_POLICIES.get(fl.merge_policy)(fl)
+    policy.mesh = mesh
+    return policy

@@ -42,7 +42,8 @@ def pearson_matrix(X: jnp.ndarray, eps: float = 1e-8) -> jnp.ndarray:
     return corr * (1 - jnp.eye(K)) + jnp.eye(K)
 
 
-def pearson_matrix_fast(X: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def pearson_matrix_fast(X: jnp.ndarray,
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Kernel-backed path (VMEM-tiled streaming accumulation)."""
     from repro.kernels.pearson.ops import pearson_corr
 
@@ -187,9 +188,10 @@ def pearson_tree(
     seed: int = 0,
     compute_dtype=None,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     fused: bool = False,
     eps: float = 1e-8,
+    mesh=None,
 ) -> jnp.ndarray:
     """Streaming tree-Pearson: stacked (K, ...) pytree -> (K, K) correlation
     without ever materializing the (K, M) client matrix.
@@ -206,6 +208,11 @@ def pearson_tree(
     benchmarks/merge_pipeline.py, not used where bit-parity with the
     per-leaf oracle is asserted). Finalization divides by the true column
     count, shared with the kernel wrapper in kernels/pearson/ops.py.
+
+    A compiled Pallas kernel cannot be partitioned by XLA, so on a
+    multi-device ``mesh`` the kernel path runs inside a ``shard_map`` with
+    every input replicated: each device gathers the client rows and
+    computes the whole (K, K) result.
     """
     from repro.kernels.pearson.ops import finalize_pearson, pearson_chunk
 
@@ -238,16 +245,25 @@ def pearson_tree(
     if fused:
         return _pearson_scan_packed(kept, eps=eps)
 
-    gram = jnp.zeros((K, K), jnp.float32)
-    sums = jnp.zeros((K,), jnp.float32)
-    n_cols = 0
-    for v in kept:
-        n_cols += int(v.shape[1])
-        if use_kernel:
-            g, s = pearson_chunk(v, interpret=interpret)
-            gram, sums = gram + g, sums + s
-        else:
-            gram, sums = _accumulate_chunk(gram, sums, v)
+    n_cols = sum(int(v.shape[1]) for v in kept)
+
+    def accumulate(*views):
+        gram = jnp.zeros((K, K), jnp.float32)
+        sums = jnp.zeros((K,), jnp.float32)
+        for v in views:
+            if use_kernel:
+                g, s = pearson_chunk(v, interpret=interpret)
+                gram, sums = gram + g, sums + s
+            else:
+                gram, sums = _accumulate_chunk(gram, sums, v)
+        return gram, sums
+
+    if use_kernel and mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+
+        accumulate = jax.shard_map(accumulate, mesh=mesh, in_specs=P(),
+                                   out_specs=P(), check_vma=False)
+    gram, sums = accumulate(*kept)
     return finalize_pearson(gram, sums, n_cols, eps=eps)
 
 

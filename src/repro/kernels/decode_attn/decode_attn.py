@@ -25,12 +25,15 @@ d therefore pays for ~d cache positions, not B * S.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 S_BLK = 512  # max S block; short caches use one 128-multiple block instead
 
@@ -85,7 +88,7 @@ def _kernel(s_blk, lengths_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
         )
 
 
-def flash_decode(q, k, v, lengths, starts, interpret: bool = True,
+def flash_decode(q, k, v, lengths, starts, interpret: Optional[bool] = None,
                  s_blk: int = S_BLK):
     """q: (B, Kv, Gp, D); k, v: (B, Kv, Sp, D); lengths/starts: (B,) int32.
     Gp multiple of 8, Sp multiple of ``s_blk``, D multiple of 128 after
@@ -122,7 +125,7 @@ def flash_decode(q, k, v, lengths, starts, interpret: bool = True,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Kv, Gp, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lengths, starts, q, k, v)
 
 
@@ -180,7 +183,7 @@ def _paged_kernel(bs, lengths_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_decode_paged(q, k, v, block_tables, lengths, block_size: int,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Block-table flash decode: q (B, Kv, Gp, D); k, v (P, Kv, bsp, D)
     global page pools (bsp = ``block_size`` sublane-padded, last block =
     trash); block_tables (B, T) int32, -1 = unallocated; lengths (B,)
@@ -223,5 +226,5 @@ def flash_decode_paged(q, k, v, block_tables, lengths, block_size: int,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Kv, Gp, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lengths, block_tables, q, k, v)

@@ -20,12 +20,15 @@ execute).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 Q_BLK = 128
 KV_BLK = 128
@@ -90,7 +93,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def flash_prefill(q, k, v, *, causal: bool, window: int, s_valid: int,
-                  scale: float, interpret: bool = True):
+                  scale: float, interpret: Optional[bool] = None):
     """q: (B, Kv, nQ, G*Q_BLK, D); k, v: (B, Kv, Sp, D); Sp % KV_BLK == 0.
     Returns o shaped like q."""
     B, Kv, nQ, GQ, D = q.shape
@@ -116,5 +119,5 @@ def flash_prefill(q, k, v, *, causal: bool, window: int, s_valid: int,
             pltpu.VMEM((GQ, 1), jnp.float32),
             pltpu.VMEM((GQ, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

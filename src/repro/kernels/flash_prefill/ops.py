@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -12,8 +13,10 @@ from repro.kernels.flash_prefill.flash_prefill import KV_BLK, Q_BLK, flash_prefi
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_prefill_attention(q, k, v, causal: bool = True, window: int = 0,
-                            interpret: bool = True):
-    """q: (B, S, Hq, D); k, v: (B, S, Kv, D) -> (B, S, Hq, D)."""
+                            interpret: Optional[bool] = None):
+    """q: (B, S, Hq, D); k, v: (B, S, Kv, D) -> (B, S, Hq, D).
+    ``interpret`` forces Pallas interpret mode (a test hook); by default it
+    follows the platform (kernels/platform.resolve_interpret)."""
     B, S, Hq, D = q.shape
     Kv = k.shape[2]
     G = Hq // Kv

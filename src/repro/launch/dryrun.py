@@ -1,5 +1,5 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, dump memory/cost/collective analysis for §Roofline.
 

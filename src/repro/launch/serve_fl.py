@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint.io import save_pytree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.experiment import ExperimentSpec, build_simulator
 from repro.launch.serve import generate
 from repro.models import model as M
@@ -646,6 +647,7 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="also write the report json here")
     args = ap.parse_args()
+    enable_compile_cache()
     report = run_serving_pipeline(
         smoke=args.smoke, num_slots=args.num_slots, capacity=args.capacity,
         num_requests=args.requests, rate=args.rate, traffic=args.traffic,

@@ -23,6 +23,7 @@ import os
 from repro.checkpoint import save_pytree
 from repro.core.merge_policy import MERGE_POLICIES
 from repro.core.scenarios import SCENARIOS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.experiment import (
     AGGREGATORS,
     ALGORITHMS,
@@ -117,6 +118,7 @@ def main():
         return
     print(spec.describe())
 
+    enable_compile_cache()
     sim, hist = run_experiment(spec)
     os.makedirs(args.out, exist_ok=True)
     tag = (f"{spec.scenario}__{spec.algo}__"
