@@ -675,11 +675,18 @@ class RoundEngine:
         return state
 
     # ------------------------------------------------------------------
-    def run(self, verbose: bool = False) -> List[RoundRecord]:
+    def run(self, verbose: bool = False, start: int = 0,
+            stop: Optional[int] = None) -> List[RoundRecord]:
+        """Run rounds [start, stop) (default: all of them) from the
+        simulator's current model, controls, weights and active set.
+        Resuming mid-run carries no in-flight stale updates, so a start
+        past 0 is refused for schedules with delays."""
         sim, fl = self.sim, self.fl
-        T = fl.num_rounds
+        T = fl.num_rounds if stop is None else stop
+        if start > 0 and self._has_delay:
+            raise ValueError("cannot resume a run with delayed updates")
         state = self._init_state()
-        t = 0
+        t = start
         while t < T:
             if t in self._merge_set:
                 state = self._run_merge_round(state, t, verbose)

@@ -293,11 +293,11 @@ class FederatedSimulator:
         self.rng = np.random.default_rng(fl.seed)
 
         key = jax.random.PRNGKey(fl.seed)
-        self.params = init_params_fn(key)
-        self.c_global, self.c_locals = init_controls(self.params, self.K)
+        params = init_params_fn(key)
         # (params, c_global, c_locals) are donated: each round's state update
         # reuses the previous round's HBM buffers instead of allocating and
         # copying — the round loop holds no stale references (see run()).
+        self.load_state(params, *init_controls(params, self.K))
         if mesh is not None:
             # Mesh-aware mode: the stacked client axis carries a
             # NamedSharding over the federation ('pod') axis, globals are
@@ -307,11 +307,6 @@ class FederatedSimulator:
             # the round loop never reshards between stages.
             rep = NamedSharding(mesh, P())
             stacked = NamedSharding(mesh, P(SH.client_axis(mesh, self.K)))
-            self.params = jax.device_put(self.params, rep)
-            self.c_global = jax.device_put(self.c_global, rep)
-            self.c_locals = jax.device_put(
-                self.c_locals, SH.client_stack_shardings(mesh, self.c_locals)
-            )
             self.round_fn = jax.jit(
                 make_round_fn(loss_fn, fl.algo),
                 donate_argnums=(0, 1, 2),
@@ -376,6 +371,19 @@ class FederatedSimulator:
             self._upload_shards()
 
     # ------------------------------------------------------------------
+    def load_state(self, params, c_global, c_locals):
+        """Set the global model and the controls, placed in this
+        simulator's layout: under a mesh the globals are replicated and the
+        stacked client axis is split over 'pod'."""
+        if self.mesh is not None:
+            rep = NamedSharding(self.mesh, P())
+            params = jax.device_put(params, rep)
+            c_global = jax.device_put(c_global, rep)
+            c_locals = jax.device_put(
+                c_locals, SH.client_stack_shardings(self.mesh, c_locals)
+            )
+        self.params, self.c_global, self.c_locals = params, c_global, c_locals
+
     def _upload_shards(self):
         """Device-resident copy of the client shards in a flat concatenated
         layout (rows of all clients back to back + per-client offset and
@@ -637,7 +645,11 @@ class FederatedSimulator:
             adv_dx, self._adv_mask,
         )
 
-    def run(self, verbose: bool = False) -> List[RoundRecord]:
+    def run(self, verbose: bool = False, start: int = 0,
+            stop: Optional[int] = None) -> List[RoundRecord]:
+        """Run rounds [start, stop) (default: all of them) from the current
+        model, controls, weights and active set, and return the history.
+        With ``load_state`` a later call resumes from any state."""
         if self.fl.pipeline == "engine":
             adv = self.adversary
             incompatible = adv is not None and (
@@ -669,10 +681,11 @@ class FederatedSimulator:
                     self, programs=getattr(self, "_engine_programs", None)
                 )
                 self._engine_programs = engine.programs
-                return engine.run(verbose=verbose)
+                return engine.run(verbose=verbose, start=start, stop=stop)
         fl = self.fl
+        stop = fl.num_rounds if stop is None else stop
         self._prefetched = None
-        for t in range(fl.num_rounds):
+        for t in range(start, stop):
             t0 = time.time()
             if self.adversary is not None:
                 drifted = self.adversary.pre_round(t, self.shards, fl.seed)
@@ -732,7 +745,7 @@ class FederatedSimulator:
                 )
             will_merge = fl.merge_enabled and t in fl.merge_at
             overlap = fl.pipeline == "device" and fl.overlap_gather
-            if overlap and not will_merge and t + 1 < fl.num_rounds:
+            if overlap and not will_merge and t + 1 < stop:
                 # double buffer: round t+1's gather is enqueued now, while
                 # round t's round_fn is still computing (async dispatch) —
                 # the gather leaves the round loop's critical path
@@ -746,7 +759,7 @@ class FederatedSimulator:
             merged: Tuple[Tuple[int, ...], ...] = ()
             if will_merge:
                 merged = self._merge(t, x_locals)
-                if overlap and t + 1 < fl.num_rounds:
+                if overlap and t + 1 < stop:
                     # shard buffers were rebuilt; gather from the merged
                     # layout (no overlap win on merge rounds)
                     self._prefetched = (t + 1, self._sample_batches(t + 1))

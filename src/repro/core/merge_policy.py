@@ -22,7 +22,8 @@ by name and selected via ``FLConfig.merge_policy``:
                   on without any population change.
 
 Register your own with ``@MERGE_POLICIES.register("name")`` — the class is
-constructed with the run's FLConfig.
+constructed with the run's FLConfig and the simulator's mesh (None when
+unmeshed).
 """
 from __future__ import annotations
 
@@ -53,12 +54,11 @@ class MergePolicy:
     """Base policy: similarity is abstract, planning is the paper's greedy
     threshold grouping over whatever similarity the subclass computes."""
 
-    # the simulator's mesh (None when unmeshed): kernel-backed similarity
-    # programs run inside it (see core/pearson.pearson_tree)
-    mesh = None
-
-    def __init__(self, fl):
+    def __init__(self, fl, mesh=None):
         self.fl = fl
+        # kernel-backed similarity programs run inside the simulator's mesh
+        # (see core/pearson.pearson_tree)
+        self.mesh = mesh
 
     def similarity(self, x_locals) -> np.ndarray:
         raise NotImplementedError
@@ -251,6 +251,4 @@ def _stacked_k(x_locals) -> int:
 
 
 def make_merge_policy(fl, mesh=None) -> MergePolicy:
-    policy = MERGE_POLICIES.get(fl.merge_policy)(fl)
-    policy.mesh = mesh
-    return policy
+    return MERGE_POLICIES.get(fl.merge_policy)(fl, mesh)

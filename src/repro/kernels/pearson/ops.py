@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.pearson.pearson import pearson_accumulate, tiling
+from repro.kernels.pearson.pearson import M_BLK, pearson_accumulate, sublane
 
 
 @jax.jit
@@ -41,18 +41,19 @@ def finalize_pearson(gram: jnp.ndarray, sums: jnp.ndarray, n_cols,
 
 
 def _pad_chunk(X: jnp.ndarray):
-    """Pad one (K, m) chunk to the kernel's tiling (``pearson.tiling``):
-    K to whole gram row blocks, m to whole feature blocks. Small chunks
-    get a single block of the next 128-multiple instead of a full feature
-    block — per-leaf padding is at most one block, never a full-matrix
-    copy."""
+    """Pad one (K, m) chunk to kernel tiling: K to a sublane multiple of its
+    dtype, m to a lane/block multiple. Small chunks get a single block of
+    the next 128-multiple instead of a full M_BLK — per-leaf padding is at
+    most one block, never a full-matrix copy."""
     K, m = X.shape
-    Kp, rows, m_blk = tiling(K, X.dtype)
-    blk = m_blk if m >= m_blk else int(np.ceil(max(m, 128) / 128) * 128)
+    sub = sublane(X.dtype)
+    Kp = int(np.ceil(max(K, sub) / sub) * sub)
+    blk = M_BLK if m >= M_BLK else int(np.ceil(max(m, 128) / 128) * 128)
     Mp = int(np.ceil(m / blk) * blk)
-    if (Kp, Mp) != (K, m):  # tile-aligned chunks skip the zero-fill copy
-        X = jnp.zeros((Kp, Mp), X.dtype).at[:K, :m].set(X)
-    return X, rows, blk
+    if (Kp, Mp) == (K, m):
+        return X, blk  # already tile-aligned: no zero-fill copy
+    Xp = jnp.zeros((Kp, Mp), X.dtype).at[:K, :m].set(X)
+    return Xp, blk
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -65,8 +66,8 @@ def pearson_chunk(X: jnp.ndarray, interpret: Optional[bool] = None):
     follows the platform (kernels/platform.resolve_interpret).
     """
     K = X.shape[0]
-    Xp, rows, blk = _pad_chunk(X)
-    gram, sums = pearson_accumulate(Xp, rows, blk, interpret=interpret)
+    Xp, blk = _pad_chunk(X)
+    gram, sums = pearson_accumulate(Xp, interpret=interpret, m_blk=blk)
     return gram[:K, :K], sums[:K, 0]
 
 
@@ -75,8 +76,8 @@ def pearson_corr(X: jnp.ndarray, interpret: Optional[bool] = None,
                  eps: float = 1e-8):
     """X: (K, M) any float dtype -> (K, K) f32 Pearson correlation matrix.
 
-    Pads K and M to the kernel's tiling (zero pads cancel in the mean/cov
-    finalization because we divide by the true M)."""
+    Pads K to a sublane multiple and M to M_BLK (zero pads cancel in the
+    mean/cov finalization because we divide by the true M)."""
     K, M = X.shape
     gram, sums = pearson_chunk(X, interpret=interpret)
     return finalize_pearson(gram, sums, M, eps=eps)

@@ -15,13 +15,9 @@ so the whole computation is a single pass over HBM at arithmetic intensity
 Inputs may be bf16 (the at-scale one-pass mode): the cast to f32 happens in
 VMEM, so HBM traffic is halved while both accumulators stay f32.
 
-Grid: (Kp / rows, M / m_blk) — the feature axis innermost and sequential
-on TPU, so the accumulators in the output VMEM blocks persist across its
-steps; they are zeroed at its step 0 via pl.when. ``tiling`` sizes both
-blocks from Kp so one step fits VMEM at every K: up to K ~ 700 the whole
-(Kp, Kp) gram is one block (one pass over HBM); past that the gram is
-split into row blocks and X streams once per row block (K = 1024 f32
-would otherwise need more than v5e's 16 MiB of scoped VMEM).
+Grid: (M / m_blk,) — sequential on TPU, so the accumulators in the output
+VMEM blocks persist across steps; they are zeroed at step 0 via pl.when.
+The whole (Kp, Kp) gram stays resident at every K, so X streams once.
 """
 from __future__ import annotations
 
@@ -30,17 +26,17 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.platform import resolve_interpret
 
-M_BLK = 2048  # largest lane-multiple block of the feature axis
-# per-step VMEM budgets (Pallas double-buffers every block, v5e scopes
-# 16 MiB): one (Kp, m_blk) input tile, counted as f32 because the kernel
-# casts it there, and one (rows, Kp) f32 gram block
-TILE_BYTES = 2 << 20
-GRAM_BYTES = 2 << 20
+M_BLK = 2048  # lane-multiple block of the feature axis; (16, 2048) f32 = 128 KiB
+# Scoped VMEM for one grid step. The default (16 MiB on v5e) is refused at
+# K=1024: the double-buffered (1024, 1024) f32 gram and (1024, 2048) input
+# tile need about 30 MiB. v5e has 128 MiB of VMEM; 64 MiB holds K=1024 in
+# f32 and bf16 at the full M_BLK (tests/test_tpu_compile.py).
+VMEM_LIMIT = 64 << 20
 
 
 def sublane(dtype) -> int:
@@ -48,23 +44,8 @@ def sublane(dtype) -> int:
     return 16 if dtype == jnp.bfloat16 else 8
 
 
-def tiling(K: int, dtype):
-    """-> (Kp, rows, m_blk): padded client count, gram rows per block and
-    the feature block, chosen from K so one grid step fits the budgets."""
-    sub = sublane(dtype)
-    Kp = int(np.ceil(max(K, sub) / sub) * sub)
-    rows = Kp
-    if 4 * Kp * Kp > GRAM_BYTES:
-        # fewest row blocks under the budget, split evenly (16-row aligned)
-        n = -(-Kp // max(16, GRAM_BYTES // (4 * Kp) // 16 * 16))
-        rows = -(-Kp // (16 * n)) * 16
-        Kp = rows * n
-    m_blk = int(np.clip(TILE_BYTES // (4 * Kp) // 128 * 128, 128, M_BLK))
-    return Kp, rows, m_blk
-
-
-def _kernel(rows, x_ref, gram_ref, sums_ref):
-    step = pl.program_id(1)
+def _kernel(x_ref, gram_ref, sums_ref):
+    step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _init():
@@ -72,41 +53,38 @@ def _kernel(rows, x_ref, gram_ref, sums_ref):
         sums_ref[...] = jnp.zeros_like(sums_ref)
 
     x = x_ref[...].astype(jnp.float32)            # (Kp, m_blk)
-    xr = x
-    if rows != x.shape[0]:
-        lo = pl.multiple_of(pl.program_id(0) * rows, 16)
-        xr = x_ref[pl.ds(lo, rows), :].astype(jnp.float32)   # (rows, m_blk)
-    # MXU: (rows, m_blk) @ (m_blk, Kp)
+    # MXU: (Kp, m_blk) @ (m_blk, Kp)
     gram_ref[...] += jax.lax.dot_general(
-        xr, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    sums_ref[...] += jnp.sum(xr, axis=1, keepdims=True)
+    sums_ref[...] += jnp.sum(x, axis=1, keepdims=True)
 
 
-def pearson_accumulate(X: jnp.ndarray, rows: int, m_blk: int,
-                       interpret: Optional[bool] = None):
-    """X: (Kp, Mp) with Kp a multiple of ``rows`` (a sublane multiple for
-    X.dtype) and Mp a multiple of ``m_blk`` (ops.py pads, sizes from
-    ``tiling``). Returns (gram (Kp,Kp), sums (Kp,1)) in f32.
+def pearson_accumulate(X: jnp.ndarray, interpret: Optional[bool] = None,
+                       m_blk: int = M_BLK):
+    """X: (Kp, Mp) with Kp a sublane multiple for X.dtype and Mp a multiple
+    of ``m_blk`` (ops.py pads). Returns (gram (Kp,Kp), sums (Kp,1)) in f32.
 
     Zero columns of padding contribute nothing to either accumulator, so the
     caller can pad each streamed chunk independently and still divide by the
-    true column count at finalization.
+    true column count at finalization. ``interpret`` forces Pallas interpret
+    mode (a test hook); by default it follows the platform.
     """
     Kp, Mp = X.shape
-    assert (Kp % sublane(X.dtype) == 0 and Kp % rows == 0
-            and Mp % m_blk == 0), (Kp, rows, Mp, m_blk)
+    assert Kp % sublane(X.dtype) == 0 and Mp % m_blk == 0, (Kp, Mp, m_blk)
+    n_blk = Mp // m_blk
     return pl.pallas_call(
-        functools.partial(_kernel, rows),
-        grid=(Kp // rows, Mp // m_blk),
-        in_specs=[pl.BlockSpec((Kp, m_blk), lambda i, j: (0, j))],
+        _kernel,
+        grid=(n_blk,),
+        in_specs=[pl.BlockSpec((Kp, m_blk), lambda i: (0, i))],
         out_specs=[
-            pl.BlockSpec((rows, Kp), lambda i, j: (i, 0)),
-            pl.BlockSpec((rows, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((Kp, Kp), lambda i: (0, 0)),
+            pl.BlockSpec((Kp, 1), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Kp, Kp), jnp.float32),
             jax.ShapeDtypeStruct((Kp, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=resolve_interpret(interpret),
     )(X)

@@ -107,6 +107,44 @@ def test_engine_segmentation_invariance():
     _assert_history_parity(ref, short)
 
 
+@pytest.mark.parametrize("pipeline", ["engine", "device"])
+def test_resume_matches_full_run(pipeline):
+    """Running [0, 2), the merge round, then [3, 6) gives the full run's
+    history: each run() call resumes from the simulator's state."""
+    ref = _make(pipeline).run()
+    sim = _make(pipeline)
+    for t0, t1 in ((0, 2), (2, 3), (3, 6)):
+        sim.run(start=t0, stop=t1)
+    _assert_history_parity(ref, sim.history)
+
+
+@pytest.mark.parametrize("ref_pipeline,pods", [("engine", 1), ("device", None)])
+def test_engine_resume_from_loaded_state(ref_pipeline, pods):
+    """Each engine round started from another run's state (load_state
+    places it in the engine's layout) reproduces that run's round: an
+    unmeshed run followed on a pods=1 mesh, and the per-round device
+    pipeline followed unmeshed."""
+    from repro.launch.mesh import make_fl_mesh
+
+    ref = _make(ref_pipeline)
+    follower = _make("engine", mesh=pods and make_fl_mesh(pods=pods))
+    for t in range(ref.fl.num_rounds):
+        follower.load_state(*jax.device_get(
+            (ref.params, ref.c_global, ref.c_locals)))
+        follower.run(start=t, stop=t + 1)
+        ref.run(start=t, stop=t + 1)
+    assert any(r.merged_groups for r in ref.history)
+    _assert_history_parity(ref.history, follower.history)
+
+
+def test_engine_resume_refused_with_delays():
+    from repro.core.engine import RoundEngine
+
+    eng = RoundEngine(_make("engine", "network_delay"))
+    with pytest.raises(ValueError, match="delayed"):
+        eng.run(start=1)
+
+
 def test_engine_merge_edge_schedules():
     """Merge at round 0 and back-to-back merge rounds exercise the
     boundary logic (zero-length segments between merges)."""

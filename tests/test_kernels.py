@@ -19,7 +19,7 @@ from repro.kernels.pearson.ref import pearson_corr_ref
 @pytest.mark.parametrize(
     "K,M",
     [(2, 64), (3, 100), (7, 2048), (10, 5000), (16, 8192), (12, 12345), (33, 4096),
-     (800, 700)],  # K=800: the gram splits into row blocks (pearson.tiling)
+     (800, 700)],  # K=800: past v5e's default scoped VMEM (pearson.VMEM_LIMIT)
 )
 def test_pearson_matches_ref(K, M, nprng):
     X = jnp.asarray(nprng.normal(size=(K, M)).astype(np.float32))
