@@ -1,0 +1,9 @@
+"""fed.device_idle: share of the traced window in which no operation ran
+on the chip (device trace)."""
+
+
+def read(run):
+    tr = run.get("trace")
+    if not tr or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
