@@ -1,6 +1,7 @@
-"""Benchmark harness — one entry per paper table/figure + the roofline
-report (deliverable d/g). Output: section banners + ``name,value,derived``
-CSV-ish lines.
+"""Benchmark harness — one entry per paper table/figure, the kernel
+micro-benchmarks and the merge pipeline. Output: section banners +
+``name,value,derived`` CSV-ish lines. The on-chip benchmark is
+``bench/run.py``.
 
   PYTHONPATH=src python -m benchmarks.run            # everything
   PYTHONPATH=src python -m benchmarks.run --fast     # skip the full fig2 FL runs
@@ -47,17 +48,6 @@ def main() -> None:
     _section("Merge pipeline — streaming/device vs materialized/host")
     from benchmarks import merge_pipeline
     merge_pipeline.run()
-
-    _section("Roofline — single-pod baselines (deliverable g)")
-    from benchmarks import roofline
-    roofline.print_table("single")
-
-    _section("Roofline — multi-pod (dry-run proof)")
-    roofline.print_table("multi")
-
-    _section("§Perf before/after — baseline vs optimized variants")
-    from benchmarks import perf_variants
-    perf_variants.run()
 
     print(f"\ntotal bench wall time: {time.time()-t0:.0f}s")
 

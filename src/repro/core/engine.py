@@ -53,6 +53,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import sharding as SH
@@ -282,21 +283,26 @@ class RoundEngine:
             """Fused merge round: train + streaming tree-Pearson +
             on-device plan + W-mix of the control state. Weights/active
             update on device; only (A, active_new) cross to host for the
-            shard bookkeeping."""
-            state, x_locals, losses = core(state, const, xrow)
+            shard bookkeeping. Its phases carry named scopes (train,
+            similarity, plan, mix), which the device trace keeps."""
+            with jax.named_scope("train"):
+                state, x_locals, losses = core(state, const, xrow)
             params, c_g, c_l, weights, active, *rest = state
-            corr = pol.device_similarity(x_locals)
-            W, A, act_new = device_merge_plan(
-                corr, active, weights,
-                threshold=thr, max_group_size=G, alpha=alpha,
-            )
+            with jax.named_scope("similarity"):
+                corr = pol.device_similarity(x_locals)
+            with jax.named_scope("plan"):
+                W, A, act_new = device_merge_plan(
+                    corr, active, weights,
+                    threshold=thr, max_group_size=G, alpha=alpha,
+                )
             # mirror the host path's "skip the apply on empty plans":
             # identity-mix (bit-exact no-op) when nothing grouped
-            has_groups = jnp.any(jnp.sum(A, axis=1) > 1.5)
-            K = A.shape[0]
-            W_eff = jnp.where(has_groups, W, jnp.eye(K, dtype=W.dtype))
-            c_l = mix_stacked_tree(W_eff, c_l)
-            weights = jnp.where(has_groups, A @ weights, weights)
+            with jax.named_scope("mix"):
+                has_groups = jnp.any(jnp.sum(A, axis=1) > 1.5)
+                K = A.shape[0]
+                W_eff = jnp.where(has_groups, W, jnp.eye(K, dtype=W.dtype))
+                c_l = mix_stacked_tree(W_eff, c_l)
+                weights = jnp.where(has_groups, A @ weights, weights)
             state = (params, c_g, c_l, weights, act_new, *rest)
             if want_locals:
                 # serving checkpoint hook: ship the round-t local models to
@@ -527,15 +533,17 @@ class RoundEngine:
         sim = self.sim
         active_pre = sim.active.copy()
         eff_mask = self._effective_masks(t0, t1, active_pre)
-        wall0 = time.time()
-        state, (p_stack, l_stack) = self.programs["segment"](
-            state, self._const(), self._xs(t0, t1, eff_mask)
-        )
-        losses_np = np.asarray(l_stack)
-        wall = (time.time() - wall0) / (t1 - t0)
+        wall0 = time.perf_counter()
+        with TraceAnnotation("fed.segment", rounds=t1 - t0):
+            state, (p_stack, l_stack) = self.programs["segment"](
+                state, self._const(), self._xs(t0, t1, eff_mask)
+            )
+            losses_np = np.asarray(l_stack)
+        wall = (time.perf_counter() - wall0) / (t1 - t0)
         for i, t in enumerate(range(t0, t1)):
-            params_t = jax.tree_util.tree_map(lambda l: l[i], p_stack)
-            acc = float(sim.eval_fn(params_t))
+            with TraceAnnotation("fed.eval", round=t):
+                params_t = jax.tree_util.tree_map(lambda l: l[i], p_stack)
+                acc = float(sim.eval_fn(params_t))
             rec = self._record(
                 t, acc, losses_np[i], active_pre, eff_mask[i], (), wall
             )
@@ -574,93 +582,113 @@ class RoundEngine:
         )
 
     def _run_merge_round(self, state, t: int, verbose: bool):
+        """One merge round in three program spans: ``fed.merge_program``
+        (the fused program, until what the host plans from is on the
+        host), ``fed.merge_host`` (the plan, the shard bookkeeping and its
+        re-upload) and ``fed.eval``."""
         sim, fl = self.sim, self.fl
         active_pre = sim.active.copy()
         eff_mask = self._effective_masks(t, t + 1, active_pre)
         xrow = self._xrow(t, eff_mask)
-        wall0 = time.time()
+        wall0 = time.perf_counter()
+        moved = 0
         if self._blocked:
-            (state, losses, A1, act1, A2, act2, rep_glob, has_rep) = \
-                self.programs["merge_blocked"](state, self._const(), xrow)
-            plan = self._decode_blocked(A1, act1, A2, act2, rep_glob)
-            sim.merge_plan = plan
-            if plan.groups:
-                # controls, weights AND active were advanced on device with
-                # fixed-shape per-block matrices; the host shell only moves
-                # shard rows and refreshes the flat row buffers (O(K))
-                sim._merge_bookkeeping(plan)
-            else:
-                sim.active = plan.active.astype(np.float32)
+            with TraceAnnotation("fed.merge_program"):
+                (state, losses, A1, act1, A2, act2, rep_glob, _has_rep) = \
+                    self.programs["merge_blocked"](state, self._const(), xrow)
+                A1, act1, A2, act2, rep_glob = jax.device_get(
+                    (A1, act1, A2, act2, rep_glob))
+            with TraceAnnotation("fed.merge_host") as span:
+                plan = self._decode_blocked(A1, act1, A2, act2, rep_glob)
+                sim.merge_plan = plan
+                if plan.groups:
+                    # controls, weights AND active were advanced on device
+                    # with fixed-shape per-block matrices; the host shell
+                    # only moves shard rows and refreshes the flat row
+                    # buffers (O(K))
+                    moved = sim._merge_bookkeeping(plan)
+                else:
+                    sim.active = plan.active.astype(np.float32)
+                span.set_metadata(groups=len(plan.groups), rows_moved=moved)
         elif self._device_plan:
-            out = self.programs["merge_device"](
-                state, self._const(), xrow
-            )
-            if self._want_locals:
-                state, losses, A, act_new, x_locals = out
-            else:
-                state, losses, A, act_new = out
-                x_locals = None
-            groups, unmerged = groups_from_assignment(
-                np.asarray(A), np.asarray(act_new)
-            )
-            plan = plan_from_groups(
-                sim.K, groups, unmerged, sim.weights.astype(np.int64),
-                alpha=fl.alpha,
-            )
-            sim.merge_plan = plan
-            if plan.groups:
-                # intermediary models mix with PRE-merge data shares; grab
-                # them before the bookkeeping folds weights into reps
-                w_pre = sim.weights.copy()
-                # controls were mixed on device; the host shell only moves
-                # shard rows, refreshes weights/active mirrors, and
-                # rebuilds the flat row buffers
-                sim._merge_bookkeeping(plan)
+            with TraceAnnotation("fed.merge_program"):
+                out = self.programs["merge_device"](
+                    state, self._const(), xrow
+                )
                 if self._want_locals:
-                    models = intermediary_models(
-                        plan, x_locals, alpha=fl.alpha, data_sizes=w_pre
-                    )
-                    sim.on_merge(t, plan, models, state[0])
-            else:
-                sim.active = plan.active.astype(np.float32)
+                    state, losses, A, act_new, x_locals = out
+                else:
+                    state, losses, A, act_new = out
+                    x_locals = None
+                A, act_new = jax.device_get((A, act_new))
+            with TraceAnnotation("fed.merge_host") as span:
+                groups, unmerged = groups_from_assignment(A, act_new)
+                plan = plan_from_groups(
+                    sim.K, groups, unmerged, sim.weights.astype(np.int64),
+                    alpha=fl.alpha,
+                )
+                sim.merge_plan = plan
+                if plan.groups:
+                    # intermediary models mix with PRE-merge data shares;
+                    # grab them before the bookkeeping folds weights into
+                    # reps
+                    w_pre = sim.weights.copy()
+                    # controls were mixed on device; the host shell only
+                    # moves shard rows, refreshes weights/active mirrors,
+                    # and rebuilds the flat device buffers
+                    moved = sim._merge_bookkeeping(plan)
+                    if self._want_locals:
+                        models = intermediary_models(
+                            plan, x_locals, alpha=fl.alpha, data_sizes=w_pre
+                        )
+                        sim.on_merge(t, plan, models, state[0])
+                else:
+                    sim.active = plan.active.astype(np.float32)
+                span.set_metadata(groups=len(plan.groups), rows_moved=moved)
         else:
-            state, losses, x_locals = self.programs["merge_host"](
-                state, self._const(), xrow
-            )
-            plan = sim.policy.merge_plan(x_locals, sim.weights, sim.active)
-            sim.merge_plan = plan
+            with TraceAnnotation("fed.merge_program"):
+                state, losses, x_locals = self.programs["merge_host"](
+                    state, self._const(), xrow
+                )
+                x_locals = jax.block_until_ready(x_locals)
+            with TraceAnnotation("fed.merge_host") as span:
+                plan = sim.policy.merge_plan(x_locals, sim.weights, sim.active)
+                sim.merge_plan = plan
 
-            def _rep(a):
-                # keep the carried state on the mesh's replicated layout so
-                # the next segment call reuses its compiled program
-                a = jnp.asarray(a)
-                if sim.mesh is not None:
-                    a = jax.device_put(a, NamedSharding(sim.mesh, P()))
-                return a
+                def _rep(a):
+                    # keep the carried state on the mesh's replicated layout
+                    # so the next segment call reuses its compiled program
+                    a = jnp.asarray(a)
+                    if sim.mesh is not None:
+                        a = jax.device_put(a, NamedSharding(sim.mesh, P()))
+                    return a
 
-            if plan.groups:
-                c_l = apply_merge_device(plan, state[2])
-                if sim.mesh is not None:
-                    # apply_merge_device lets GSPMD infer the output layout;
-                    # re-pin the stacked-client contract so the next segment
-                    # call matches its compiled input shardings
-                    c_l = jax.device_put(
-                        c_l, SH.client_stack_shardings(sim.mesh, c_l)
-                    )
-                w_pre = sim.weights.copy()
-                sim._merge_bookkeeping(plan)
-                if self._want_locals:
-                    models = intermediary_models(
-                        plan, x_locals, alpha=fl.alpha, data_sizes=w_pre
-                    )
-                    sim.on_merge(t, plan, models, state[0])
-                state = (state[0], state[1], c_l,
-                         _rep(sim.weights), _rep(sim.active), *state[5:])
-            else:
-                sim.active = plan.active.astype(np.float32)
-                state = (*state[:4], _rep(sim.active), *state[5:])
-        acc = float(sim.eval_fn(state[0]))
-        wall = time.time() - wall0
+                if plan.groups:
+                    c_l = apply_merge_device(plan, state[2])
+                    if sim.mesh is not None:
+                        # apply_merge_device lets GSPMD infer the output
+                        # layout; re-pin the stacked-client contract so the
+                        # next segment call matches its compiled input
+                        # shardings
+                        c_l = jax.device_put(
+                            c_l, SH.client_stack_shardings(sim.mesh, c_l)
+                        )
+                    w_pre = sim.weights.copy()
+                    moved = sim._merge_bookkeeping(plan)
+                    if self._want_locals:
+                        models = intermediary_models(
+                            plan, x_locals, alpha=fl.alpha, data_sizes=w_pre
+                        )
+                        sim.on_merge(t, plan, models, state[0])
+                    state = (state[0], state[1], c_l,
+                             _rep(sim.weights), _rep(sim.active), *state[5:])
+                else:
+                    sim.active = plan.active.astype(np.float32)
+                    state = (*state[:4], _rep(sim.active), *state[5:])
+                span.set_metadata(groups=len(plan.groups), rows_moved=moved)
+        with TraceAnnotation("fed.eval", round=t):
+            acc = float(sim.eval_fn(state[0]))
+        wall = time.perf_counter() - wall0
         rec = self._record(
             t, acc, np.asarray(losses), active_pre, eff_mask[0],
             plan.groups, wall
@@ -689,7 +717,8 @@ class RoundEngine:
         t = start
         while t < T:
             if t in self._merge_set:
-                state = self._run_merge_round(state, t, verbose)
+                with TraceAnnotation("fed.merge_round", round=t):
+                    state = self._run_merge_round(state, t, verbose)
                 t += 1
             else:
                 boundary = min([b for b in self._merge_set if b > t] + [T])

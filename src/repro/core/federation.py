@@ -34,6 +34,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import sharding as SH
@@ -245,7 +246,14 @@ class RoundRecord:
     RAN: on merge rounds the clients that trained and uploaded are the
     pre-merge active set, so ``active_nodes``/``updates_sent``/``mean_loss``
     are snapshotted before ``_merge`` shrinks the mask; the post-merge
-    population is ``active_nodes_end`` (== ``active_nodes`` otherwise)."""
+    population is ``active_nodes_end`` (== ``active_nodes`` otherwise).
+
+    ``wall_s`` is host time (``time.perf_counter``). In the compiled
+    engine a training round's ``wall_s`` is its share of the scan
+    segment, from the program call to the losses on the host, and leaves
+    out the round's evaluation; a merge round's covers the whole round,
+    its evaluation included. The per-round pipelines time each round
+    whole, evaluation included."""
     round: int
     accuracy: float
     mean_loss: float
@@ -394,26 +402,31 @@ class FederatedSimulator:
         on device — no host->device transfer per round. In mesh-aware mode
         the row dimension is sharded over the 'pod' axis (merging moves
         rows between clients but preserves the total, so the sharding
-        survives merge rounds)."""
-        xs = np.concatenate([x for x, _ in self.shards])
-        ys = np.concatenate([y for _, y in self.shards])
-        lens = np.asarray([len(y) for _, y in self.shards], np.int32)
-        offs = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
-        if self.mesh is not None:
-            rep = NamedSharding(self.mesh, P())
-            self._shard_x = jax.device_put(
-                xs, SH.row_sharding(self.mesh, len(xs))
+        survives merge rounds). The program span ``fed.upload_shards``
+        counts the bytes put on the device (``nbytes``)."""
+        with TraceAnnotation("fed.upload_shards") as span:
+            xs = np.concatenate([x for x, _ in self.shards])
+            ys = np.concatenate([y for _, y in self.shards])
+            lens = np.asarray([len(y) for _, y in self.shards], np.int32)
+            offs = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
+            span.set_metadata(
+                nbytes=xs.nbytes + ys.nbytes + lens.nbytes + offs.nbytes
             )
-            self._shard_y = jax.device_put(
-                ys, SH.row_sharding(self.mesh, len(ys))
-            )
-            self._shard_len = jax.device_put(lens, rep)
-            self._shard_off = jax.device_put(offs, rep)
-        else:
-            self._shard_x = jnp.asarray(xs)
-            self._shard_y = jnp.asarray(ys)
-            self._shard_len = jnp.asarray(lens)
-            self._shard_off = jnp.asarray(offs)
+            if self.mesh is not None:
+                rep = NamedSharding(self.mesh, P())
+                self._shard_x = jax.device_put(
+                    xs, SH.row_sharding(self.mesh, len(xs))
+                )
+                self._shard_y = jax.device_put(
+                    ys, SH.row_sharding(self.mesh, len(ys))
+                )
+                self._shard_len = jax.device_put(lens, rep)
+                self._shard_off = jax.device_put(offs, rep)
+            else:
+                self._shard_x = jnp.asarray(xs)
+                self._shard_y = jnp.asarray(ys)
+                self._shard_len = jnp.asarray(lens)
+                self._shard_off = jnp.asarray(offs)
 
     def _sample_batches(self, t: int):
         """(K, steps, B, ...) batches drawn from each client's shard.
@@ -567,9 +580,11 @@ class FederatedSimulator:
         member data; retired members keep their slot (fixed shapes
         everywhere) but give up their rows — otherwise the flat device
         buffers hold every merged row twice and the gather keeps sampling
-        retired clients."""
+        retired clients. Returns the rows moved into representatives."""
+        moved = 0
         for group in plan.groups:
             rep = group[0]
+            moved += sum(len(self.shards[j][1]) for j in group[1:])
             xs = np.concatenate([self.shards[j][0] for j in group])
             ys = np.concatenate([self.shards[j][1] for j in group])
             self.shards[rep] = (xs, ys)
@@ -580,6 +595,7 @@ class FederatedSimulator:
         self.active = plan.active.astype(np.float32)
         if self.fl.pipeline in ("device", "engine"):
             self._upload_shards()  # representative shards grew
+        return moved
 
     # ------------------------------------------------------------------
     def _round_record(self, t: int, accuracy, losses, active_round,
@@ -686,7 +702,7 @@ class FederatedSimulator:
         stop = fl.num_rounds if stop is None else stop
         self._prefetched = None
         for t in range(start, stop):
-            t0 = time.time()
+            t0 = time.perf_counter()
             if self.adversary is not None:
                 drifted = self.adversary.pre_round(t, self.shards, fl.seed)
                 if drifted is not None:
@@ -768,7 +784,7 @@ class FederatedSimulator:
             acc = self.eval_fn(self.params)
             rec = self._round_record(
                 t, acc, losses, active_round, round_mask, merged,
-                time.time() - t0,
+                time.perf_counter() - t0,
             )
             self.history.append(rec)
             if verbose:

@@ -126,6 +126,7 @@ def flash_decode(q, k, v, lengths, starts, interpret: Optional[bool] = None,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Kv, Gp, D), q.dtype),
         interpret=resolve_interpret(interpret),
+        name="decode_attn",
     )(lengths, starts, q, k, v)
 
 
@@ -227,4 +228,5 @@ def flash_decode_paged(q, k, v, block_tables, lengths, block_size: int,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Kv, Gp, D), q.dtype),
         interpret=resolve_interpret(interpret),
+        name="paged_decode_attn",
     )(lengths, block_tables, q, k, v)

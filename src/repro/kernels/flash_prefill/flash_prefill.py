@@ -120,4 +120,5 @@ def flash_prefill(q, k, v, *, causal: bool, window: int, s_valid: int,
             pltpu.VMEM((GQ, D), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name="flash_prefill",
     )(q, k, v)

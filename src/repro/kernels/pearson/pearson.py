@@ -87,4 +87,5 @@ def pearson_accumulate(X: jnp.ndarray, interpret: Optional[bool] = None,
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=resolve_interpret(interpret),
+        name="pearson_gram",
     )(X)

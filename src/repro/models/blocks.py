@@ -137,15 +137,18 @@ def layer_fwd(params, cfg, mixer_type: str, x, positions, return_state: bool):
 
 def layer_decode(params, cfg, mixer_type: str, x, state, pos):
     dec = MIXERS[mixer_type][2]
-    y, new_state = dec(params["mixer"], cfg, L.rmsnorm(params["norm1"], x), state, pos)
+    with jax.named_scope("mixer"):
+        y, new_state = dec(params["mixer"], cfg, L.rmsnorm(params["norm1"], x),
+                           state, pos)
     x = x + y
     kind = _ffn_kind(cfg)
-    if kind == "mlp":
-        x = x + L.mlp_fwd(params["ffn"], L.rmsnorm(params["norm2"], x))
-    elif kind == "moe":
-        moe = MOE.moe_fwd_ep if getattr(cfg, "moe_impl", "gspmd") == "ep" else MOE.moe_fwd
-        y, _ = moe(params["ffn"], cfg, L.rmsnorm(params["norm2"], x))
-        x = x + y
+    with jax.named_scope("ffn"):
+        if kind == "mlp":
+            x = x + L.mlp_fwd(params["ffn"], L.rmsnorm(params["norm2"], x))
+        elif kind == "moe":
+            moe = MOE.moe_fwd_ep if getattr(cfg, "moe_impl", "gspmd") == "ep" else MOE.moe_fwd
+            y, _ = moe(params["ffn"], cfg, L.rmsnorm(params["norm2"], x))
+            x = x + y
     return x, new_state
 
 

@@ -124,9 +124,13 @@ def prefill(params, cfg, batch):
 
 
 def decode_step(params, cfg, states, tokens, pos):
-    """tokens (B,) int32, pos (B,) int32 absolute position of the new token."""
-    x = jnp.take(params["embed"], tokens[:, None], axis=0)
-    x, new_states = B.blocks_decode(params["blocks"], cfg, x, states, pos)
-    x = L.rmsnorm(params["final_norm"], x)
-    logits = (x @ params["lm_head"])[:, 0]
+    """tokens (B,) int32, pos (B,) int32 absolute position of the new token.
+    Its phases carry named scopes: embed, layers, head."""
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens[:, None], axis=0)
+    with jax.named_scope("layers"):
+        x, new_states = B.blocks_decode(params["blocks"], cfg, x, states, pos)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x)
+        logits = (x @ params["lm_head"])[:, 0]
     return logits, new_states

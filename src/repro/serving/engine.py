@@ -78,6 +78,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.launch.serve import states_from_prefill
@@ -339,38 +340,44 @@ def _paged_step(cfg: ModelConfig, n_rows: int, t_view: int):
     host block table is broadcast to the per-layer cache dicts and dropped
     from the returned arena. ``t_view`` is the depth bucket in PAGES —
     rows deeper than ``t_view * bs`` never occur inside the bucket, so
-    slicing table columns is exact."""
+    slicing table columns is exact. Its phases carry named scopes (view,
+    the model's decode step, write_back, sample)."""
 
     def step(params, arena, tok, pos, active, bt):
         view = []
-        for (mtype, _n), st in zip(B.runs(cfg), arena):
-            if mtype == "attn":
-                runL = st["length"].shape[0]
-                view.append({
-                    "k": st["k"], "v": st["v"],
-                    "block_tables": jnp.broadcast_to(
-                        bt[None], (runL, n_rows, t_view)
-                    ),
-                    "length": st["length"][:, :n_rows],
-                })
-            else:
-                view.append(
-                    jax.tree_util.tree_map(lambda a: a[:, :n_rows], st)
-                )
+        with jax.named_scope("view"):
+            for (mtype, _n), st in zip(B.runs(cfg), arena):
+                if mtype == "attn":
+                    runL = st["length"].shape[0]
+                    view.append({
+                        "k": st["k"], "v": st["v"],
+                        "block_tables": jnp.broadcast_to(
+                            bt[None], (runL, n_rows, t_view)
+                        ),
+                        "length": st["length"][:, :n_rows],
+                    })
+                else:
+                    view.append(
+                        jax.tree_util.tree_map(lambda a: a[:, :n_rows], st)
+                    )
         logits, new_view = M.decode_step(params, cfg, tuple(view), tok, pos)
-        new_view = _mask_lengths(cfg, new_view, active)
         out = []
-        for (mtype, _n), full, v in zip(B.runs(cfg), arena, new_view):
-            if mtype == "attn":
-                out.append({
-                    "k": v["k"], "v": v["v"],  # pools updated in place
-                    "length": full["length"].at[:, :n_rows].set(v["length"]),
-                })
-            else:
-                out.append(jax.tree_util.tree_map(
-                    lambda a, b: a.at[:, :n_rows].set(b), full, v
-                ))
-        return jnp.argmax(logits, -1).astype(jnp.int32), tuple(out)
+        with jax.named_scope("write_back"):
+            new_view = _mask_lengths(cfg, new_view, active)
+            for (mtype, _n), full, v in zip(B.runs(cfg), arena, new_view):
+                if mtype == "attn":
+                    out.append({
+                        "k": v["k"], "v": v["v"],  # pools updated in place
+                        "length": full["length"].at[:, :n_rows].set(
+                            v["length"]),
+                    })
+                else:
+                    out.append(jax.tree_util.tree_map(
+                        lambda a, b: a.at[:, :n_rows].set(b), full, v
+                    ))
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        return nxt, tuple(out)
 
     return jax.jit(step, donate_argnums=(1,))
 
@@ -629,9 +636,20 @@ class ServeEngine:
         (already *finished* if max_new_tokens == 1 — the first token comes
         from prefill; ``rejected=True`` if the request can never fit), or
         None when no slot is free (paged: or the page pool cannot cover
-        the request's worst-case reservation)."""
-        if self.kv_layout == "paged":
-            return self._try_admit_paged(req, now)
+        the request's worst-case reservation). The program span
+        ``serve.admit`` carries the request's id, its prompt length and
+        whether it took a slot (``admitted``)."""
+        with TraceAnnotation("serve.admit", rid=req.rid,
+                             prompt=len(req.prompt)) as span:
+            if self.kv_layout == "paged":
+                a = self._try_admit_paged(req, now)
+            else:
+                a = self._try_admit_contiguous(req, now)
+            span.set_metadata(admitted=int(a is not None and not a.rejected))
+            return a
+
+    def _try_admit_contiguous(self, req: Request, now: float = 0.0
+                              ) -> Optional[ActiveRequest]:
         L = len(req.prompt)
         if L + req.max_new_tokens > self.capacity:
             # over capacity for this engine: graceful reject, no slot state
@@ -742,7 +760,7 @@ class ServeEngine:
         return mask
 
     # ------------------------------------------------------------------
-    def _step_batched(self, now: float) -> List[ActiveRequest]:
+    def _step_batched(self, now: float) -> Tuple[List[ActiveRequest], dict]:
         na = self.num_active
         # bucket floor of 2: XLA's batch-1 path is measurably slower than
         # one masked dead lane on CPU, and the floor halves the program count
@@ -755,6 +773,7 @@ class ServeEngine:
                 max(_next_pow2(max_pos + 1), min(16, self._depth)),
                 self._depth,
             )
+        shape = dict(bucket=n_rows, view=s_view, bt_upload=0)
         active = np.zeros(n_rows, np.int32)
         active[:na] = 1
         nxt, self.arena = _batched_step(self.cfg, n_rows, s_view)(
@@ -764,7 +783,6 @@ class ServeEngine:
         )
         nxt = np.asarray(nxt)
         self.steps += 1
-        finished: List[ActiveRequest] = []
         for i in range(na):
             a = self.slots[i]
             a.tokens.append(int(nxt[i]))
@@ -773,28 +791,32 @@ class ServeEngine:
         # swap-remove evictions, highest row first, to keep the prefix
         # compact: the last active row fills each hole on device and host
         done_rows = [i for i in range(na) if self.slots[i].done]
-        cur = na
-        for i in sorted(done_rows, reverse=True):
-            a = self.slots[i]
-            a.finished_at = now
-            finished.append(a)
-            last = cur - 1
-            self.arena = _evict_move(self.cfg)(
-                self.arena, jnp.int32(last), jnp.int32(i)
-            )
-            if self.debug_poison:
-                # row `last` is the vacated lane after the swap-remove
-                self.arena = _poison_row(self.cfg)(
-                    self.arena, jnp.int32(last)
+        if not done_rows:
+            return [], shape
+        finished: List[ActiveRequest] = []
+        with TraceAnnotation("serve.evict", rows=len(done_rows)):
+            cur = na
+            for i in sorted(done_rows, reverse=True):
+                a = self.slots[i]
+                a.finished_at = now
+                finished.append(a)
+                last = cur - 1
+                self.arena = _evict_move(self.cfg)(
+                    self.arena, jnp.int32(last), jnp.int32(i)
                 )
-            self.slots[i] = self.slots[last]
-            self.slots[last] = None
-            self._tok[i] = self._tok[last]
-            self._pos[i] = self._pos[last]
-            cur -= 1
-        return finished
+                if self.debug_poison:
+                    # row `last` is the vacated lane after the swap-remove
+                    self.arena = _poison_row(self.cfg)(
+                        self.arena, jnp.int32(last)
+                    )
+                self.slots[i] = self.slots[last]
+                self.slots[last] = None
+                self._tok[i] = self._tok[last]
+                self._pos[i] = self._pos[last]
+                cur -= 1
+        return finished, shape
 
-    def _step_paged(self, now: float) -> List[ActiveRequest]:
+    def _step_paged(self, now: float) -> Tuple[List[ActiveRequest], dict]:
         # every page a row will ever write was drawn at admission, so the
         # block table only mutates on admit/evict and the device upload
         # below is a cache hit on every pure-decode step
@@ -813,11 +835,13 @@ class ServeEngine:
         active[:na] = 1
         key = (n_rows, t_view)
         ent = self._bt_dev.get(key)
-        if ent is None or ent[0] != self._bt_version:
+        upload = ent is None or ent[0] != self._bt_version
+        if upload:
             bt_dev = jnp.asarray(self._bt[:n_rows, :t_view])
             self._bt_dev[key] = (self._bt_version, bt_dev)
         else:
             bt_dev = ent[1]
+        shape = dict(bucket=n_rows, view=t_view, bt_upload=int(upload))
         nxt, self.arena = _paged_step(self.cfg, n_rows, t_view)(
             self.params, self.arena,
             jnp.asarray(self._tok[:n_rows]), jnp.asarray(self._pos[:n_rows]),
@@ -825,41 +849,45 @@ class ServeEngine:
         )
         nxt = np.asarray(nxt)
         self.steps += 1
-        finished: List[ActiveRequest] = []
         for i in range(na):
             a = self.slots[i]
             a.tokens.append(int(nxt[i]))
             self._tok[i] = int(nxt[i])
             self._pos[i] += 1
         done_rows = [i for i in range(na) if self.slots[i].done]
-        cur = na
-        for i in sorted(done_rows, reverse=True):
-            a = self.slots[i]
-            a.finished_at = now
-            finished.append(a)
-            freed = self._row_blocks[i]
-            self.allocator.free(freed)
-            if self.debug_poison and freed:
-                self.arena = _poison_blocks(self.cfg)(
-                    self.arena, jnp.asarray(self._block_mask(freed))
+        if not done_rows:
+            return [], shape
+        finished: List[ActiveRequest] = []
+        with TraceAnnotation("serve.evict", rows=len(done_rows)):
+            cur = na
+            for i in sorted(done_rows, reverse=True):
+                a = self.slots[i]
+                a.finished_at = now
+                finished.append(a)
+                freed = self._row_blocks[i]
+                self.allocator.free(freed)
+                if self.debug_poison and freed:
+                    self.arena = _poison_blocks(self.cfg)(
+                        self.arena, jnp.asarray(self._block_mask(freed))
+                    )
+                last = cur - 1
+                self.arena = _paged_evict(self.cfg)(
+                    self.arena, jnp.int32(last), jnp.int32(i)
                 )
-            last = cur - 1
-            self.arena = _paged_evict(self.cfg)(
-                self.arena, jnp.int32(last), jnp.int32(i)
-            )
-            self._bt[i] = self._bt[last]
-            self._bt[last] = -1
-            self._bt_version += 1
-            self._row_blocks[i] = self._row_blocks[last]
-            self._row_blocks[last] = []
-            self.slots[i] = self.slots[last]
-            self.slots[last] = None
-            self._tok[i] = self._tok[last]
-            self._pos[i] = self._pos[last]
-            cur -= 1
-        return finished
+                self._bt[i] = self._bt[last]
+                self._bt[last] = -1
+                self._bt_version += 1
+                self._row_blocks[i] = self._row_blocks[last]
+                self._row_blocks[last] = []
+                self.slots[i] = self.slots[last]
+                self.slots[last] = None
+                self._tok[i] = self._tok[last]
+                self._pos[i] = self._pos[last]
+                cur -= 1
+        return finished, shape
 
-    def _step_vmap(self, now: float) -> List[ActiveRequest]:
+    def _step_vmap(self, now: float) -> Tuple[List[ActiveRequest], dict]:
+        shape = dict(bucket=self.num_slots, view=self.capacity, bt_upload=0)
         nxt, self.arena = _fused_step(self.cfg)(
             self.params, self.arena, jnp.asarray(self._tok),
             jnp.asarray(self._pos)
@@ -877,18 +905,27 @@ class ServeEngine:
                 active.finished_at = now
                 finished.append(active)
                 self.slots[i] = None  # evict; state overwritten on re-admit
-        return finished
+        return finished, shape
 
     def step(self, now: float = 0.0) -> List[ActiveRequest]:
         """One fused decode step over all active slots; returns requests
-        that finished this step (their slots are freed). No-op when idle."""
-        if self.num_active == 0:
+        that finished this step (their slots are freed). No-op when idle.
+        The program span ``serve.step`` carries the active rows, the row
+        bucket and depth view the step ran at, and whether the block table
+        went to the device (``bt_upload``); evictions nest in it as
+        ``serve.evict``."""
+        na = self.num_active
+        if na == 0:
             return []
-        if self.kv_layout == "paged":
-            return self._step_paged(now)
-        if self.fused_mode == "batched":
-            return self._step_batched(now)
-        return self._step_vmap(now)
+        with TraceAnnotation("serve.step", rows=na) as span:
+            if self.kv_layout == "paged":
+                finished, shape = self._step_paged(now)
+            elif self.fused_mode == "batched":
+                finished, shape = self._step_batched(now)
+            else:
+                finished, shape = self._step_vmap(now)
+            span.set_metadata(**shape)
+        return finished
 
     def run_to_completion(self, now: float = 0.0) -> List[ActiveRequest]:
         """Drain all active slots (no new admissions)."""

@@ -9,6 +9,7 @@ The topology is described inside module-scoped fixtures, never while a
 module is imported: only one process may load the TPU library, and only
 the worker that is given this file does."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -43,12 +44,14 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled_kernel(fn, *args):
+def _compiled_kernel(name, fn, *args):
     """Compile ``fn`` for the described chip; assert the Pallas kernel is
-    in the program as a compiled custom call (not interpreted). JAX still
-    runs on the CPU here, so each test forces the compiled kernel."""
+    in the program as a compiled custom call (not interpreted) under its
+    stable ``name``, the name the device trace gives it. JAX still runs
+    on the CPU here, so each test forces the compiled kernel."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call", text), name
 
 
 def _cnn_clients(K, sharding_of):
@@ -63,7 +66,7 @@ def _cnn_clients(K, sharding_of):
 def test_pearson_tree_cnn_k10(one_chip):
     stacked = _cnn_clients(10, lambda nd: one_chip)
     _compiled_kernel(
-        lambda x: pearson_tree(x, use_kernel=True, interpret=False), stacked)
+        "pearson_gram", lambda x: pearson_tree(x, use_kernel=True, interpret=False), stacked)
 
 
 def test_pearson_tree_cnn_pod_sharded(topo):
@@ -73,7 +76,7 @@ def test_pearson_tree_cnn_pod_sharded(topo):
     stacked = _cnn_clients(
         8, lambda nd: NamedSharding(mesh, P("pod", *([None] * (nd - 1)))))
     _compiled_kernel(
-        lambda x: pearson_tree(x, use_kernel=True, interpret=False,
+        "pearson_gram", lambda x: pearson_tree(x, use_kernel=True, interpret=False,
                                mesh=mesh),
         stacked)
 
@@ -81,7 +84,8 @@ def test_pearson_tree_cnn_pod_sharded(topo):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_pearson_chunk_k1024(one_chip, dtype):
     x = jax.ShapeDtypeStruct((1024, 1 << 16), dtype, sharding=one_chip)
-    _compiled_kernel(lambda v: pearson_chunk(v, interpret=False), x)
+    _compiled_kernel("pearson_gram",
+                     lambda v: pearson_chunk(v, interpret=False), x)
 
 
 def test_decode_attention_qwen(one_chip):
@@ -89,7 +93,7 @@ def test_decode_attention_qwen(one_chip):
     bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
     lengths = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
     _compiled_kernel(
-        lambda q, k, v, n: decode_attention(q, k, v, n, backend="pallas"),
+        "decode_attn", lambda q, k, v, n: decode_attention(q, k, v, n, backend="pallas"),
         bf(B, QWEN.num_heads, D), bf(B, S, QWEN.num_kv_heads, D),
         bf(B, S, QWEN.num_kv_heads, D), lengths)
 
@@ -101,7 +105,7 @@ def test_paged_decode_attention_qwen(one_chip, page):
     bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
     _compiled_kernel(
-        lambda q, k, v, bt, n: paged_decode_attention(q, k, v, bt, n,
+        "paged_decode_attn", lambda q, k, v, bt, n: paged_decode_attention(q, k, v, bt, n,
                                                       backend="pallas"),
         bf(B, QWEN.num_heads, D), bf(pages, page, QWEN.num_kv_heads, D),
         bf(pages, page, QWEN.num_kv_heads, D), i32(B, cap // page), i32(B))
@@ -111,6 +115,6 @@ def test_flash_prefill_attention_qwen(one_chip):
     S, D = 512, QWEN.head_dim
     bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
     _compiled_kernel(
-        lambda q, k, v: flash_prefill_attention(q, k, v, interpret=False),
+        "flash_prefill", lambda q, k, v: flash_prefill_attention(q, k, v, interpret=False),
         bf(1, S, QWEN.num_heads, D),
         bf(1, S, QWEN.num_kv_heads, D), bf(1, S, QWEN.num_kv_heads, D))
