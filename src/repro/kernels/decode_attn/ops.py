@@ -113,28 +113,41 @@ def decode_attention(q, k, v, lengths, window: int = 0,
                           resolved == "interpret")
 
 
+# bytes of K one block of the paged kernel fetches: big enough that a grid
+# step's fixed cost is small against its copies, small enough that K and V,
+# double-buffered, take a few MiB of VMEM
+PAGED_BLOCK_BYTES = 256 * 1024
+
+
+def pages_per_block(block_size: int, kv_heads: int, head_dim: int,
+                    itemsize: int, table_len: int) -> int:
+    """Whole pages per grid step of the paged decode kernel, from the pool's
+    shapes: K blocks of about ``PAGED_BLOCK_BYTES`` (8 pages of 16 tokens
+    at 8 heads of 128 in bf16), capped at the table width."""
+    page = block_size * kv_heads * head_dim * itemsize
+    return max(1, min(PAGED_BLOCK_BYTES // page, table_len))
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _pallas_paged_decode(q, k_pool, v_pool, block_tables, lengths,
                          interpret: bool):
     B, Hq, D = q.shape
-    P, bs, Kv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    bs, Kv = k_pool.shape[1], k_pool.shape[2]
     G = Hq // Kv
     Gp = int(np.ceil(max(G, 8) / 8) * 8)
-    bsp = int(np.ceil(bs / 8) * 8)   # sublane-pad the page axis
-    Dp = int(np.ceil(D / 128) * 128)
 
-    # pre-scale by the TRUE head dim (padding would otherwise skew the scale)
+    # pre-scale by the head dim, and pad each head's query group to Gp
+    # rows; the pools go to the kernel as they are
     qg = (q * (1.0 / np.sqrt(D))).astype(q.dtype).reshape(B, Kv, G, D)
-    qp = jnp.zeros((B, Kv, Gp, Dp), q.dtype).at[:, :, :G, :D].set(qg)
-    kt = jnp.moveaxis(k_pool, 2, 1)  # (P, Kv, bs, D)
-    vt = jnp.moveaxis(v_pool, 2, 1)
-    kp = jnp.zeros((P, Kv, bsp, Dp), k_pool.dtype).at[:, :, :bs, :D].set(kt)
-    vp = jnp.zeros((P, Kv, bsp, Dp), v_pool.dtype).at[:, :, :bs, :D].set(vt)
-
-    out = flash_decode_paged(qp, kp, vp, block_tables.astype(jnp.int32),
-                             lengths.astype(jnp.int32), block_size=bs,
+    qp = jnp.zeros((B, Kv, Gp, D), q.dtype).at[:, :, :G].set(qg)
+    ppb = pages_per_block(bs, Kv, D, k_pool.dtype.itemsize,
+                          block_tables.shape[1])
+    out = flash_decode_paged(qp.reshape(B, Kv * Gp, D), k_pool, v_pool,
+                             block_tables.astype(jnp.int32),
+                             lengths.astype(jnp.int32), ppb,
                              interpret=interpret)
-    return out[:, :, :G, :D].reshape(B, Hq, D)
+    return out.reshape(B, Kv, Gp, D)[:, :, :G].reshape(B, Hq, D).astype(
+        q.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,

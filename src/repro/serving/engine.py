@@ -81,6 +81,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
+from repro.kernels.decode_attn.ops import pages_per_block
 from repro.launch.serve import states_from_prefill
 from repro.models import blocks as B
 from repro.models import model as M
@@ -604,6 +605,12 @@ class ServeEngine:
                 else:
                     arena.append(st)
             self.arena = tuple(arena)
+            # (kv heads, head dim, itemsize) of a pool page, for the step's
+            # counters of what the paged decode kernel reads
+            self._kv_page = next(
+                ((st["k"].shape[3], st["k"].shape[4], st["k"].dtype.itemsize)
+                 for (mtype, _n), st in zip(B.runs(cfg), arena)
+                 if mtype == "attn"), None)
         elif fused_mode == "batched":
             # one batched decode state, slot axis inside each leaf
             self.arena = tuple(M.init_decode(cfg, self.num_slots, capacity))
@@ -841,7 +848,8 @@ class ServeEngine:
             self._bt_dev[key] = (self._bt_version, bt_dev)
         else:
             bt_dev = ent[1]
-        shape = dict(bucket=n_rows, view=t_view, bt_upload=int(upload))
+        shape = dict(bucket=n_rows, view=t_view, bt_upload=int(upload),
+                     **self._kv_counters(na, n_rows, t_view))
         nxt, self.arena = _paged_step(self.cfg, n_rows, t_view)(
             self.params, self.arena,
             jnp.asarray(self._tok[:n_rows]), jnp.asarray(self._pos[:n_rows]),
@@ -886,6 +894,19 @@ class ServeEngine:
                 cur -= 1
         return finished, shape
 
+    def _kv_counters(self, na: int, n_rows: int, t_view: int) -> dict:
+        """What a paged step's attention reads in each layer: the live
+        pages of its rows (``kv_pages``), and the page blocks the paged
+        decode kernel's grid visits (``kv_blocks``), live or not."""
+        if self._kv_page is None:
+            return {}
+        bs = self.block_size
+        # a windowed row attends at most its ring
+        live = np.minimum(self._pos[:na] + 1, self._row_cap)
+        ppb = pages_per_block(bs, *self._kv_page, t_view)
+        return dict(kv_pages=int((-(-live // bs)).sum()),
+                    kv_blocks=n_rows * -(-t_view // ppb))
+
     def _step_vmap(self, now: float) -> Tuple[List[ActiveRequest], dict]:
         shape = dict(bucket=self.num_slots, view=self.capacity, bt_upload=0)
         nxt, self.arena = _fused_step(self.cfg)(
@@ -912,8 +933,10 @@ class ServeEngine:
         that finished this step (their slots are freed). No-op when idle.
         The program span ``serve.step`` carries the active rows, the row
         bucket and depth view the step ran at, and whether the block table
-        went to the device (``bt_upload``); evictions nest in it as
-        ``serve.evict``."""
+        went to the device (``bt_upload``); a paged step also carries the
+        live KV pages its rows attend and the page blocks the paged decode
+        kernel visits (``kv_pages``, ``kv_blocks``). Evictions nest in it
+        as ``serve.evict``."""
         na = self.num_active
         if na == 0:
             return []

@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from _hyp import given, settings, st
+from repro.kernels.decode_attn.decode_attn import page_fetches
 from repro.kernels.decode_attn.ops import paged_decode_attention
 from repro.kernels.decode_attn.ref import (
     gather_paged_kv,
@@ -188,25 +189,91 @@ def test_paged_attention_decode_windowed(bs):
 # ---------------------------------------------------------------------------
 
 
-def test_paged_kernel_matches_reference_fragmented():
-    rng = np.random.default_rng(7)
-    B, Hq, Kv, D, bs, T = 4, 8, 2, 64, 4, 4
-    P = B * T + 1
-    q = jnp.asarray(rng.normal(size=(B, Hq, D)).astype(np.float32))
-    k_pool = jnp.asarray(rng.normal(size=(P, bs, Kv, D)).astype(np.float32))
-    v_pool = jnp.asarray(rng.normal(size=(P, bs, Kv, D)).astype(np.float32))
-    # fragmented: pages dealt round-robin, plus some unallocated tails
+def _round_robin_case():
+    """Pages dealt round-robin, with unallocated tails on two rows."""
+    B, T, bs = 4, 4, 4
     bt = np.arange(B * T).reshape(T, B).T.astype(np.int32).copy()
     bt[0, 3] = -1  # row 0: only 3 pages live
     bt[2, 2:] = -1  # row 2: only 2 pages live
-    lengths = jnp.asarray([bs * 3, bs * T, bs * 2 - 1, 1], jnp.int32)
+    return bt, [bs * 3, bs * T, bs * 2 - 1, 1]
+
+
+# (G, Kv, D, bs, T, lengths, dtype); the kernel takes ``ppb`` whole pages a
+# grid step (ops.pages_per_block), and each case names where its lengths
+# fall against that block: 0 (a dead row), 1, a block edge, the full table.
+# Pages of 128-wide heads are copied by the kernel itself, the others
+# through the grid pipeline (decode_attn.page_is_tiled): both are here.
+PAGED_KERNEL_CASES = [
+    (4, 2, 64, 4, 4, "round-robin", jnp.float32),
+    (1, 16, 128, 16, 7, [0, 1, 2 * 2 * 16, 7 * 16], jnp.float32),    # ppb 2
+    (2, 8, 128, 16, 20, [8 * 16, 20 * 16, 17, 0], jnp.bfloat16),     # ppb 8
+    (7, 4, 80, 8, 30, [25 * 8, 30 * 8, 1, 97], jnp.float32),         # ppb 25
+    (12, 1, 64, 4, 6, [6 * 4, 1, 0, 13], jnp.float32),               # ppb = T
+    (1, 16, 128, 16, 10, [4 * 16, 1, 10 * 16], jnp.bfloat16),        # ppb 4
+    (12, 4, 64, 8, 9, [9 * 8, 0, 8], jnp.bfloat16),                  # ppb = T
+    (2, 16, 64, 16, 12, [8 * 16, 12 * 16, 0, 33], jnp.bfloat16),     # ppb 8
+]
+
+
+@pytest.mark.parametrize("G,Kv,D,bs,T,lengths,dtype", PAGED_KERNEL_CASES)
+def test_paged_kernel_matches_reference_fragmented(G, Kv, D, bs, T, lengths,
+                                                   dtype):
+    rng = np.random.default_rng(7)
+    if lengths == "round-robin":
+        bt, lengths = _round_robin_case()
+    else:
+        # fragmented: a shuffled page-id space, -1 past each row's live pages
+        bt = rng.permutation(len(lengths) * T).reshape(-1, T).astype(np.int32)
+        for b, n in enumerate(lengths):
+            bt[b, -(-n // bs):] = -1
+    B = len(lengths)
+    P = B * T + 1
+    normal = lambda *s: jnp.asarray(rng.normal(size=s), dtype)
+    q = normal(B, G * Kv, D)
+    k_pool, v_pool = normal(P, bs, Kv, D), normal(P, bs, Kv, D)
+    lengths = jnp.asarray(lengths, jnp.int32)
     bt = jnp.asarray(bt)
 
-    want = paged_decode_attention_ref(q, k_pool, v_pool, bt, lengths)
-    got = paged_decode_attention(q, k_pool, v_pool, bt, lengths,
-                                 backend="interpret")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    got = np.asarray(paged_decode_attention(q, k_pool, v_pool, bt, lengths,
+                                            backend="interpret"), np.float32)
+    if dtype == jnp.float32:
+        want = paged_decode_attention_ref(q, k_pool, v_pool, bt, lengths)
+        rtol = 2e-5
+    else:
+        # Pools are read exactly into f32 on both sides. The kernel attends
+        # with the query pre-scaled by 1/sqrt(D) and rounded to bf16, so the
+        # reference gets that same query in f32; what differs then is the
+        # f32 summation order (the f32 tolerance) and the kernel's final
+        # rounding of its f32 output to bf16, at most half a bf16 ulp:
+        # 2^-8 of the value.
+        seen = (q * (1.0 / np.sqrt(D))).astype(dtype).astype(jnp.float32)
+        want = paged_decode_attention_ref(seen * np.float32(np.sqrt(D)),
+                                          k_pool, v_pool, bt, lengths)
+        rtol = 2e-5 + 2.0 ** -8
+    want = np.asarray(want, np.float32)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=rtol, atol=2e-5)
+    # a dead row finalizes to zeros, never NaN
+    assert (got[~live] == 0).all()
+
+
+def test_page_fetches_stay_in_the_pool():
+    """Every page the kernel's grid fetches is a pool page. A dead row of
+    the engine's padded batch keeps the stale position of the row that
+    left it and an all -1 table; the chip's DMA bounds check halts the
+    core on a page index outside the pool, where interpret mode clamps."""
+    bs, ppb = 4, 3
+    bt = np.array([[5, 2, 7, 1, 0, 0, 0],
+                   [-1] * 7,                  # dead row, stale length
+                   [3, 6, -1, -1, -1, -1, -1]], np.int32)
+    lengths = jnp.asarray([4 * bs, 6 * bs + 1, 2 * bs - 1], jnp.int32)
+    pages = np.asarray(page_fetches(jnp.asarray(bt), lengths, bs, ppb))
+    assert pages.shape == (3, 9)
+    assert (pages >= 0).all()
+    # a live slot fetches its table entry; a slot past the row's length,
+    # or past the table's end, repeats what it fetched last
+    np.testing.assert_array_equal(pages[0], [5, 2, 7, 1, 2, 7, 1, 2, 7])
+    np.testing.assert_array_equal(pages[2, :3], [3, 6, 0])
 
 
 # ---------------------------------------------------------------------------

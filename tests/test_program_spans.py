@@ -9,6 +9,7 @@ import pytest
 from bench import spans as S
 from bench import trace as T
 from repro import program_spans
+from repro.kernels.decode_attn.ops import pages_per_block
 from repro.models import model as M
 from repro.serving.engine import ServeEngine
 from repro.serving.fl_model import serve_config
@@ -120,6 +121,19 @@ def test_serving_spans(tmp_path):
     assert sum(g["n"] for g in step["by"]["bucket"].values()) == len(rows)
     # the table goes up after each admission and eviction, not every step
     assert 1 <= step["args"]["bt_upload"] < len(rows)
+    # a request decodes at positions L .. L+n-2 (prefill gave its first
+    # token); each step's rows attend ceil((pos+1)/bs) live pages
+    bs = eng.block_size
+    assert step["args"]["kv_pages"] == sum(
+        -(-(pos + 1) // bs)
+        for r in reqs
+        for pos in range(len(r.prompt), len(r.prompt) + r.max_new_tokens - 1))
+    # the kernel's grid: every row of the bucket, every block of the view
+    kv = eng.arena[0]["k"]  # (layers, pages, bs, Kv, D)
+    assert step["args"]["kv_blocks"] == sum(
+        e["bucket"] * -(-e["view"] // pages_per_block(
+            bs, kv.shape[3], kv.shape[4], kv.dtype.itemsize, e["view"]))
+        for e in events["serve.step"])
     ev = sp["serve.evict"]
     assert ev["args"]["rows"] == evicted == len(reqs)
     assert ev["within"] == {"serve.step": ev["n"]}

@@ -52,6 +52,20 @@ def _compiled_kernel(name, fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert re.search(rf"%{name}(\.\d+)? = .*custom-call", text), name
+    return text
+
+
+def _made_arrays(text, count):
+    """The instructions of a compiled program, fused ones included, that
+    are not parameters and make an array of ``count`` elements."""
+    made = []
+    for m in re.finditer(r"^\s*(?:ROOT )?(%\S+) = (.*?) ([\w-]+)\(", text, re.M):
+        if m.group(3) == "parameter":
+            continue
+        for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2)):
+            if np.prod([int(d) for d in dims.split(",") if d]) == count:
+                made.append(m.group(1))
+    return made
 
 
 def _cnn_clients(K, sharding_of):
@@ -104,11 +118,28 @@ def test_paged_decode_attention_qwen(one_chip, page):
     pages = B * cap // page + 1  # the serving pool plus its trash page
     bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    pool = (pages, page, QWEN.num_kv_heads, D)
+    text = _compiled_kernel(
+        "paged_decode_attn", lambda q, k, v, bt, n: paged_decode_attention(q, k, v, bt, n,
+                                                      backend="pallas"),
+        bf(B, QWEN.num_heads, D), bf(*pool), bf(*pool), i32(B, cap // page), i32(B))
+    # the kernel reads the pools where they lie: no copy, transpose or pad
+    assert _made_arrays(text, np.prod(pool)) == []
+
+
+@pytest.mark.parametrize("arch", ["granite-moe-1b-a400m", "recurrentgemma-2b"])
+def test_paged_decode_attention_untiled_pages(one_chip, arch):
+    """Pages that are not whole tiles of the HBM layout (64-wide heads; one
+    bf16 KV head) reach the kernel through its grid pipeline."""
+    cfg = get_config(arch)
+    B, D, cap, page = 8, cfg.head_dim, 1024, 16
+    pool = (B * cap // page + 1, page, cfg.num_kv_heads, D)
+    bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
     _compiled_kernel(
         "paged_decode_attn", lambda q, k, v, bt, n: paged_decode_attention(q, k, v, bt, n,
                                                       backend="pallas"),
-        bf(B, QWEN.num_heads, D), bf(pages, page, QWEN.num_kv_heads, D),
-        bf(pages, page, QWEN.num_kv_heads, D), i32(B, cap // page), i32(B))
+        bf(B, cfg.num_heads, D), bf(*pool), bf(*pool), i32(B, cap // page), i32(B))
 
 
 def test_flash_prefill_attention_qwen(one_chip):
