@@ -135,8 +135,8 @@ def flash_decode(q, k, v, lengths, starts, interpret: Optional[bool] = None,
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(bs, ppb, Kv, Gp, by_dma, lengths_ref, table_ref, q_ref,
-                  *refs):
+def _paged_kernel(bs, ppb, Kv, Gp, by_dma, layer_ref, lengths_ref, table_ref,
+                  q_ref, *refs):
     if by_dma:
         (k_pool, v_pool, o_ref, k_buf, v_buf, k_sem, v_sem, state_ref,
          m_ref, l_ref, acc_ref) = refs
@@ -163,7 +163,8 @@ def _paged_kernel(bs, ppb, Kv, Gp, by_dma, lengths_ref, table_ref, q_ref,
                 for pool, buf, sem in ((k_pool, k_buf, k_sem),
                                        (v_pool, v_buf, v_sem)):
                     act(pltpu.make_async_copy(
-                        pool.at[page], buf.at[slot, rows], sem.at[slot]))
+                        pool.at[layer_ref[0], page], buf.at[slot, rows],
+                        sem.at[slot]))
                 return carry
 
             jax.lax.fori_loop(0, n, body, 0)
@@ -211,7 +212,7 @@ def _paged_kernel(bs, ppb, Kv, Gp, by_dma, lengths_ref, table_ref, q_ref,
             for_pages(b, j, slot, lambda c: c.wait())
             k, v = k_buf[slot], v_buf[slot]                  # (N, Kv, D)
         else:
-            k, v = (jnp.concatenate([r[0] for r in refs_], axis=0)
+            k, v = (jnp.concatenate([r[0, 0] for r in refs_], axis=0)
                     for refs_ in (k_refs, v_refs))
 
         # all heads at once: one (Kv*Gp, D) x (D, N*Kv) product, masked
@@ -278,30 +279,32 @@ def page_fetches(block_tables, lengths, block_size: int,
     return jnp.where(last >= 0, pages, 0).reshape(B, nb * ppb)
 
 
-def flash_decode_paged(q, k_pool, v_pool, block_tables, lengths,
+def flash_decode_paged(q, k_pool, v_pool, layer, block_tables, lengths,
                        pages_per_block: int,
                        interpret: Optional[bool] = None):
-    """Block-table flash decode over the page pools as the arena holds
-    them: q (B, Kv*Gp, D), each head's query group pre-scaled and padded to
-    Gp rows (a multiple of 8); k_pool, v_pool (P, bs, Kv, D) (last page =
-    trash); block_tables (B, T) int32, -1 = unallocated; lengths (B,)
-    int32 over *logical* slots (slot l lives at page bt[b, l // bs]).
-    Returns (B, Kv*Gp, D) float32.
+    """Block-table flash decode over one layer of the stacked page pools,
+    as the arena holds them: q (B, Kv*Gp, D), each head's query group
+    pre-scaled and padded to Gp rows (a multiple of 8); k_pool, v_pool
+    (L, P, bs, Kv, D), every layer's pool (last page = trash); layer
+    (1,) int32, the pool the kernel reads; block_tables (B, T) int32, -1 =
+    unallocated; lengths (B,) int32 over *logical* slots (slot l lives at
+    page bt[b, l // bs]). Returns (B, Kv*Gp, D) float32.
 
     One grid step is one row and one block of ``pages_per_block`` table
-    entries, all heads. The kernel reads the pools where they lie, and a
-    page past a row's length is neither fetched nor computed. Where a page
-    is whole tiles (``page_is_tiled``), the kernel copies each live page
-    itself into a double-buffered VMEM block, one live block ahead, from
-    the scalar-prefetched table. Otherwise a copy cannot slice a page out
-    of the pool, and each pool is an operand once per page slot of a
-    block, a one-page block whose index map reads ``page_fetches``: the
-    grid pipeline fetches a block while the previous one computes, and
+    entries, all heads. The kernel reads the pools where they lie, the
+    layer a scalar-prefetched index, and a page past a row's length is
+    neither fetched nor computed. Where a page is whole tiles
+    (``page_is_tiled``), the kernel copies each live page itself into a
+    double-buffered VMEM block, one live block ahead, from the
+    scalar-prefetched table. Otherwise a copy cannot slice a page out of
+    the pool, and each pool is an operand once per page slot of a block, a
+    one-page block whose index map reads the layer and ``page_fetches``:
+    the grid pipeline fetches a block while the previous one computes, and
     pads the page in VMEM. Where both work the kernel's own copies win: at
     Qwen3's widths on a v5e the pipeline took 34 us a call against 20, and
     it lets XLA stage a pool it finds in HBM into VMEM whole."""
     B, R, D = q.shape
-    bs, Kv = k_pool.shape[1], k_pool.shape[2]
+    bs, Kv = k_pool.shape[2], k_pool.shape[3]
     Gp = R // Kv
     T = block_tables.shape[1]
     ppb = pages_per_block
@@ -323,8 +326,9 @@ def flash_decode_paged(q, k_pool, v_pool, block_tables, lengths,
     else:
         def page_spec(i):
             return pl.BlockSpec(
-                (1, bs, Kv, D),
-                lambda b, j, _lengths, pages: (pages[b, j * ppb + i], 0, 0, 0))
+                (1, 1, bs, Kv, D),
+                lambda b, j, layer, _lengths, pages: (
+                    layer[0], pages[b, j * ppb + i], 0, 0, 0))
 
         table = page_fetches(block_tables, lengths, bs, ppb)
         pool_specs = [page_spec(i) for i in range(ppb)] * 2
@@ -334,7 +338,7 @@ def flash_decode_paged(q, k_pool, v_pool, block_tables, lengths,
     return pl.pallas_call(
         functools.partial(_paged_kernel, bs, ppb, Kv, Gp, by_dma),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, pl.cdiv(T, ppb)),
             in_specs=[q_spec] + pool_specs,
             out_specs=q_spec,
@@ -345,4 +349,4 @@ def flash_decode_paged(q, k_pool, v_pool, block_tables, lengths,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=resolve_interpret(interpret),
         name="paged_decode_attn",
-    )(lengths, table, q, *pools)
+    )(layer, lengths, table, q, *pools)

@@ -129,10 +129,10 @@ def pages_per_block(block_size: int, kv_heads: int, head_dim: int,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_paged_decode(q, k_pool, v_pool, block_tables, lengths,
+def _pallas_paged_decode(q, k_pool, v_pool, layer, block_tables, lengths,
                          interpret: bool):
     B, Hq, D = q.shape
-    bs, Kv = k_pool.shape[1], k_pool.shape[2]
+    bs, Kv = k_pool.shape[2], k_pool.shape[3]
     G = Hq // Kv
     Gp = int(np.ceil(max(G, 8) / 8) * 8)
 
@@ -143,6 +143,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, block_tables, lengths,
     ppb = pages_per_block(bs, Kv, D, k_pool.dtype.itemsize,
                           block_tables.shape[1])
     out = flash_decode_paged(qp.reshape(B, Kv * Gp, D), k_pool, v_pool,
+                             jnp.reshape(layer, (1,)).astype(jnp.int32),
                              block_tables.astype(jnp.int32),
                              lengths.astype(jnp.int32), ppb,
                              interpret=interpret)
@@ -152,23 +153,28 @@ def _pallas_paged_decode(q, k_pool, v_pool, block_tables, lengths,
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            window: int = 0, backend: str = "auto",
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None, layer=None):
     """Paged flash decode: q (B, Hq, D); k_pool, v_pool (P, bs, Kv, D)
-    global page pools (last block = trash); block_tables (B, T) int32
-    (-1 = unallocated); lengths (B,) int32 over logical slots. Backend
-    selection per module docstring. The ring-cache callers always pass
-    ``window=0`` (every resident slot is inside the window by cache
-    construction — see ``layers.attention_decode``); the kernel therefore
-    only implements length masking, while the reference path keeps the
-    ``window`` kwarg for direct oracle use."""
+    global page pools (last block = trash), or every layer's pools stacked
+    (L, P, bs, Kv, D) with ``layer`` (an int32 scalar) naming the one to
+    read, in place; block_tables (B, T) int32 (-1 = unallocated); lengths
+    (B,) int32 over logical slots. Backend selection per module docstring.
+    The ring-cache callers always pass ``window=0`` (every resident slot is
+    inside the window by cache construction — see
+    ``layers.attention_decode``); the kernel therefore only implements
+    length masking, while the reference path keeps the ``window`` kwarg
+    for direct oracle use."""
     resolved = resolve_decode_backend(backend, interpret)
+    if k_pool.ndim == 4:  # one layer's pools: the stack of one
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     if resolved == "reference":
-        return paged_decode_attention_ref(q, k_pool, v_pool, block_tables,
-                                          lengths, window=window)
+        return paged_decode_attention_ref(q, k_pool[layer], v_pool[layer],
+                                          block_tables, lengths,
+                                          window=window)
     if window > 0:
         raise NotImplementedError(
             "paged flash decode handles windows via ring lengths, not a "
             "start offset; pass window=0 with window-clamped lengths"
         )
-    return _pallas_paged_decode(q, k_pool, v_pool, block_tables, lengths,
-                                resolved == "interpret")
+    return _pallas_paged_decode(q, k_pool, v_pool, layer, block_tables,
+                                lengths, resolved == "interpret")

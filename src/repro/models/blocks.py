@@ -190,7 +190,13 @@ def blocks_fwd(block_params, cfg, x, positions, return_state: bool = False,
 def blocks_decode(block_params, cfg, x, states, pos):
     """One-token pass; states is a tuple of stacked per-run states."""
     new_states = []
-    for (mtype, _count), stacked, run_state in zip(runs(cfg), block_params, states):
+    for (mtype, count), stacked, run_state in zip(runs(cfg), block_params, states):
+        if "block_tables" in run_state:
+            x, new_run_state = _decode_carrying_pools(
+                stacked, cfg, mtype, count, x, run_state, pos)
+            new_states.append(new_run_state)
+            continue
+
         def body(xc, lp_state, _mtype=mtype):
             lp, st = lp_state
             xc, new_st = layer_decode(lp, cfg, _mtype, xc, st, pos)
@@ -200,6 +206,29 @@ def blocks_decode(block_params, cfg, x, states, pos):
                                         unroll=FLAGS.scan_unroll())
         new_states.append(new_run_state)
     return x, tuple(new_states)
+
+
+def _decode_carrying_pools(stacked, cfg, mtype, count, x, run_state, pos):
+    """A paged attention run: the stacked page pools ride in the loop's
+    carry, and each layer writes its token into its own pool and reads
+    it there, by its index. Scanned as ``xs``/``ys`` instead, every step
+    would slice each layer's pool out and stack new ones: the whole pools
+    copied about three times a token. Only the parameters and the rest
+    of the state (block tables, lengths) are per-layer slices."""
+    rest = {n: a for n, a in run_state.items() if n not in ("k", "v")}
+
+    def body(carry, xs):
+        xc, k, v = carry
+        lp, st, layer = xs
+        xc, new = layer_decode(lp, cfg, mtype, xc,
+                               dict(st, k=k, v=v, layer=layer), pos)
+        return (xc, new["k"], new["v"]), {n: new[n] for n in st}
+
+    (x, k, v), rest = jax.lax.scan(
+        body, (x, run_state["k"], run_state["v"]),
+        (stacked, rest, jnp.arange(count, dtype=jnp.int32)),
+        unroll=FLAGS.scan_unroll())
+    return x, dict(rest, k=k, v=v)
 
 
 def init_decode_states(cfg, batch: int, max_len: int, dtype):

@@ -307,7 +307,9 @@ def attention_decode(params, cfg, x, cache, pos):
     (last block = trash), block_tables: int32[B, T] (-1 = unallocated),
     length: int32[B]}; dispatched by the ``block_tables`` key. Logical
     slot l of row b lives at pool page ``block_tables[b, l // bs]``,
-    offset ``l % bs`` — same ring semantics, one indirection deeper."""
+    offset ``l % bs`` — same ring semantics, one indirection deeper. The
+    pools are every layer's of the run stacked, and the ``layer`` key names
+    the one this layer reads and writes (``blocks.blocks_decode``)."""
     if "block_tables" in cache:
         return _attention_decode_paged(params, cfg, x, cache, pos)
     B = x.shape[0]
@@ -349,12 +351,18 @@ def _attention_decode_paged(params, cfg, x, cache, pos):
     slots. A row whose write lands on an unallocated table entry (-1 —
     only inactive lanes; live rows hold their full page reservation from
     admission) is redirected to the trash page, so it can never corrupt
-    another row's pages."""
+    another row's pages.
+
+    The pools are every layer's of the run, stacked (L, P+1, bs, Kv, D),
+    as the layer loop carries them: the token is written at
+    ``[layer, page, offset]`` by one scatter a pool, in place, and the
+    kernel reads that layer's pool where it lies."""
     B = x.shape[0]
     q, k, v = _qkv(params, cfg, x, pos[:, None] if pos.ndim == 1 else pos)
-    k_pool, v_pool = cache["k"], cache["v"]  # (P+1, bs, Kv, D)
+    k_pool, v_pool = cache["k"], cache["v"]  # (L, P+1, bs, Kv, D)
+    layer = cache["layer"]
     bt = cache["block_tables"]               # (B, T) int32
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     W = bt.shape[1] * bs                     # physical slots in the view
     # logical ring size: same rule as the contiguous cache. W may exceed
     # min(window, max_row_len) by page-size rounding; slots >= C are never
@@ -363,12 +371,12 @@ def _attention_decode_paged(params, cfg, x, cache, pos):
     length = cache["length"]
     slot = jnp.mod(length, C)                # (B,) logical write slot
     rows = jnp.arange(B)
-    trash = k_pool.shape[0] - 1
+    trash = k_pool.shape[1] - 1
     wblk = bt[rows, slot // bs]
     wblk = jnp.where(wblk >= 0, wblk, trash)
-    ck = k_pool.at[wblk, slot % bs].set(k[:, 0])
-    cv = v_pool.at[wblk, slot % bs].set(v[:, 0])
-    new_cache = {"k": ck, "v": cv, "block_tables": bt, "length": length + 1}
+    ck = k_pool.at[layer, wblk, slot % bs].set(k[:, 0])
+    cv = v_pool.at[layer, wblk, slot % bs].set(v[:, 0])
+    new_cache = dict(cache, k=ck, v=cv, length=length + 1)
 
     backend = _resolve_decode_backend(cfg)
     if backend != "jnp":
@@ -379,7 +387,7 @@ def _attention_decode_paged(params, cfg, x, cache, pos):
 
         eff_len = jnp.minimum(length + 1, C).astype(jnp.int32)
         out = paged_decode_attention(q[:, 0], ck, cv, bt, eff_len, window=0,
-                                     backend=backend)
+                                     backend=backend, layer=layer)
         y = out.reshape(B, 1, cfg.q_dim) @ params["wo"]
         return y, new_cache
 
@@ -388,7 +396,7 @@ def _attention_decode_paged(params, cfg, x, cache, pos):
     # exactly masked)
     from repro.kernels.decode_attn.ref import gather_paged_kv
 
-    gk, gv = gather_paged_kv(ck, cv, bt)     # (B, W, Kv, D)
+    gk, gv = gather_paged_kv(ck[layer], cv[layer], bt)  # (B, W, Kv, D)
     mask = _ring_decode_mask(length, slot, C, pos, cfg.window_size, width=W)
     out = _attend(q, gk, gv, mask, cfg)      # (B, 1, Hq, D)
     y = out.reshape(B, 1, cfg.q_dim) @ params["wo"]

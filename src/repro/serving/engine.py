@@ -262,7 +262,11 @@ def _evict_move(cfg: ModelConfig):
 # pools, length: (runL, num_slots)}; recurrent runs keep the contiguous
 # (runL, num_slots, ...) layout. Block tables live on the HOST (the engine's
 # ``_bt``) and are passed into each program — the device never owns them,
-# so allocator moves are plain numpy writes, not compiled programs.
+# so allocator moves are plain numpy writes, not compiled programs. The
+# decode step's layer loop carries a run's stacked pools whole: each layer
+# scatters its token into its own pool in place and the kernel reads that
+# pool by its layer index, so the pools leave the step in the donated
+# arena's buffers and the step copies no pool, of a layer or the stack.
 
 
 @functools.lru_cache(maxsize=32)
@@ -339,9 +343,10 @@ def _paged_step(cfg: ModelConfig, n_rows: int, t_view: int):
 
     ONE ragged batched ``decode_step`` over the occupancy bucket; the
     host block table is broadcast to the per-layer cache dicts and dropped
-    from the returned arena. ``t_view`` is the depth bucket in PAGES —
-    rows deeper than ``t_view * bs`` never occur inside the bucket, so
-    slicing table columns is exact. Its phases carry named scopes (view,
+    from the returned arena, and the pools come back as the layer loop
+    carried them, updated in place. ``t_view`` is the depth bucket in
+    PAGES — rows deeper than ``t_view * bs`` never occur inside the
+    bucket, so slicing table columns is exact. Its phases carry named scopes (view,
     the model's decode step, write_back, sample)."""
 
     def step(params, arena, tok, pos, active, bt):
@@ -368,7 +373,7 @@ def _paged_step(cfg: ModelConfig, n_rows: int, t_view: int):
             for (mtype, _n), full, v in zip(B.runs(cfg), arena, new_view):
                 if mtype == "attn":
                     out.append({
-                        "k": v["k"], "v": v["v"],  # pools updated in place
+                        "k": v["k"], "v": v["v"],  # the carried pools
                         "length": full["length"].at[:, :n_rows].set(
                             v["length"]),
                     })

@@ -36,6 +36,7 @@ from repro.kernels.decode_attn.ref import (
     paged_decode_attention_ref,
 )
 from repro.launch.serve import generate
+from repro.models import blocks
 from repro.models import layers as L
 from repro.models import model as M
 from repro.serving.engine import ServeEngine
@@ -151,10 +152,11 @@ def _paged_layer_case(window: int, bs: int, seed: int):
         for s in range(C):
             k_pool[ids[b, s // bs], s % bs] = ck[b, s]
             v_pool[ids[b, s // bs], s % bs] = cv[b, s]
-    pcache["k"] = jnp.asarray(k_pool)
-    pcache["v"] = jnp.asarray(v_pool)
+    pcache["k"] = jnp.asarray(k_pool)[None]  # a run of one layer
+    pcache["v"] = jnp.asarray(v_pool)[None]
     pcache["block_tables"] = jnp.asarray(ids)
     pcache["length"] = jnp.asarray(lengths)
+    pcache["layer"] = jnp.int32(0)
 
     x = jnp.asarray(rng.normal(size=(B, 1, cfg.d_model)).astype(np.float32))
     pos = jnp.asarray(lengths)
@@ -168,7 +170,8 @@ def _paged_layer_case(window: int, bs: int, seed: int):
     np.testing.assert_array_equal(np.asarray(np_["length"]),
                                   np.asarray(nc["length"]))
     # the written-through pool holds the same logical cache
-    gk, _gv = gather_paged_kv(np_["k"], np_["v"], np_["block_tables"])
+    gk, _gv = gather_paged_kv(np_["k"][0], np_["v"][0],
+                              np_["block_tables"])
     np.testing.assert_allclose(np.asarray(gk)[:, :C], np.asarray(nc["k"]),
                                rtol=0, atol=0)
 
@@ -198,26 +201,56 @@ def _round_robin_case():
     return bt, [bs * 3, bs * T, bs * 2 - 1, 1]
 
 
-# (G, Kv, D, bs, T, lengths, dtype); the kernel takes ``ppb`` whole pages a
-# grid step (ops.pages_per_block), and each case names where its lengths
-# fall against that block: 0 (a dead row), 1, a block edge, the full table.
-# Pages of 128-wide heads are copied by the kernel itself, the others
-# through the grid pipeline (decode_attn.page_is_tiled): both are here.
+# (G, Kv, D, bs, T, lengths, dtype, layer); the kernel takes ``ppb`` whole
+# pages a grid step (ops.pages_per_block), and each case names where its
+# lengths fall against that block: 0 (a dead row), 1, a block edge, the
+# full table. Pages of 128-wide heads are copied by the kernel itself, the
+# others through the grid pipeline (decode_attn.page_is_tiled): both are
+# here. ``layer`` None is one layer's pools (P, bs, Kv, D); else the pools
+# are three layers' stacked (3, P, bs, Kv, D), as the layer loop carries
+# them, and the kernel reads ``layer`` in place, first, middle and last.
+# Every layer holds different data, so reading the wrong one fails.
 PAGED_KERNEL_CASES = [
-    (4, 2, 64, 4, 4, "round-robin", jnp.float32),
-    (1, 16, 128, 16, 7, [0, 1, 2 * 2 * 16, 7 * 16], jnp.float32),    # ppb 2
-    (2, 8, 128, 16, 20, [8 * 16, 20 * 16, 17, 0], jnp.bfloat16),     # ppb 8
-    (7, 4, 80, 8, 30, [25 * 8, 30 * 8, 1, 97], jnp.float32),         # ppb 25
-    (12, 1, 64, 4, 6, [6 * 4, 1, 0, 13], jnp.float32),               # ppb = T
-    (1, 16, 128, 16, 10, [4 * 16, 1, 10 * 16], jnp.bfloat16),        # ppb 4
-    (12, 4, 64, 8, 9, [9 * 8, 0, 8], jnp.bfloat16),                  # ppb = T
-    (2, 16, 64, 16, 12, [8 * 16, 12 * 16, 0, 33], jnp.bfloat16),     # ppb 8
+    pytest.param(4, 2, 64, 4, 4, "round-robin", jnp.float32, None,
+                 id="4-2-64-4-4-round-robin-float32"),
+    pytest.param(1, 16, 128, 16, 7, [0, 1, 2 * 2 * 16, 7 * 16],
+                 jnp.float32, None,                                  # ppb 2
+                 id="1-16-128-16-7-lengths1-float32"),
+    pytest.param(2, 8, 128, 16, 20, [8 * 16, 20 * 16, 17, 0],
+                 jnp.bfloat16, None,                                 # ppb 8
+                 id="2-8-128-16-20-lengths2-bfloat16"),
+    pytest.param(7, 4, 80, 8, 30, [25 * 8, 30 * 8, 1, 97],
+                 jnp.float32, None,                                  # ppb 25
+                 id="7-4-80-8-30-lengths3-float32"),
+    pytest.param(12, 1, 64, 4, 6, [6 * 4, 1, 0, 13],
+                 jnp.float32, None,                                  # ppb = T
+                 id="12-1-64-4-6-lengths4-float32"),
+    pytest.param(1, 16, 128, 16, 10, [4 * 16, 1, 10 * 16],
+                 jnp.bfloat16, None,                                 # ppb 4
+                 id="1-16-128-16-10-lengths5-bfloat16"),
+    pytest.param(12, 4, 64, 8, 9, [9 * 8, 0, 8],
+                 jnp.bfloat16, None,                                 # ppb = T
+                 id="12-4-64-8-9-lengths6-bfloat16"),
+    pytest.param(2, 16, 64, 16, 12, [8 * 16, 12 * 16, 0, 33],
+                 jnp.bfloat16, None,                                 # ppb 8
+                 id="2-16-64-16-12-lengths7-bfloat16"),
+    pytest.param(2, 8, 128, 16, 20, [8 * 16, 20 * 16, 17, 0],
+                 jnp.bfloat16, 0,                          # the kernel's copies
+                 id="stacked3-layer0-2-8-128-16-20-lengths2-bfloat16"),
+    pytest.param(4, 2, 64, 4, 4, "round-robin", jnp.float32, 1,  # the pipeline
+                 id="stacked3-layer1-4-2-64-4-4-round-robin-float32"),
+    pytest.param(1, 16, 128, 16, 10, [4 * 16, 1, 10 * 16],
+                 jnp.bfloat16, 2,                          # the kernel's copies
+                 id="stacked3-layer2-1-16-128-16-10-lengths5-bfloat16"),
+    pytest.param(12, 4, 64, 8, 9, [9 * 8, 0, 8], jnp.bfloat16, 2,  # the pipeline
+                 id="stacked3-layer2-12-4-64-8-9-lengths6-bfloat16"),
 ]
 
 
-@pytest.mark.parametrize("G,Kv,D,bs,T,lengths,dtype", PAGED_KERNEL_CASES)
+@pytest.mark.parametrize("G,Kv,D,bs,T,lengths,dtype,layer",
+                         PAGED_KERNEL_CASES)
 def test_paged_kernel_matches_reference_fragmented(G, Kv, D, bs, T, lengths,
-                                                   dtype):
+                                                   dtype, layer):
     rng = np.random.default_rng(7)
     if lengths == "round-robin":
         bt, lengths = _round_robin_case()
@@ -230,12 +263,17 @@ def test_paged_kernel_matches_reference_fragmented(G, Kv, D, bs, T, lengths,
     P = B * T + 1
     normal = lambda *s: jnp.asarray(rng.normal(size=s), dtype)
     q = normal(B, G * Kv, D)
-    k_pool, v_pool = normal(P, bs, Kv, D), normal(P, bs, Kv, D)
+    stack = () if layer is None else (3,)
+    k_pool, v_pool = normal(*stack, P, bs, Kv, D), normal(*stack, P, bs, Kv, D)
     lengths = jnp.asarray(lengths, jnp.int32)
     bt = jnp.asarray(bt)
 
+    kw = {} if layer is None else {"layer": jnp.int32(layer)}
     got = np.asarray(paged_decode_attention(q, k_pool, v_pool, bt, lengths,
-                                            backend="interpret"), np.float32)
+                                            backend="interpret", **kw),
+                     np.float32)
+    if layer is not None:  # the reference reads the one layer's pools
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
     if dtype == jnp.float32:
         want = paged_decode_attention_ref(q, k_pool, v_pool, bt, lengths)
         rtol = 2e-5
@@ -255,6 +293,52 @@ def test_paged_kernel_matches_reference_fragmented(G, Kv, D, bs, T, lengths,
     np.testing.assert_allclose(got[live], want[live], rtol=rtol, atol=2e-5)
     # a dead row finalizes to zeros, never NaN
     assert (got[~live] == 0).all()
+
+
+def test_carried_pool_write_touches_only_its_layer():
+    """The layer loop carries every layer's pools stacked, and layer ``l``
+    writes its token through one scatter a pool at ``[l, page, offset]``:
+    every other layer's pages, and every other slot of its own, stay
+    bit-identical, and the layer attends exactly as over its own pool
+    alone, a run of one layer. A dead row's all -1 table sends its write
+    to the trash page."""
+    cfg = serve_config("qwen3-1.7b")
+    rng = np.random.default_rng(11)
+    p = L.attention_init(jax.random.PRNGKey(11), cfg, jnp.float32)
+    n_layers, B, bs, T = 3, 4, 4, 3
+    trash = B * T
+    pool_shape = (n_layers, trash + 1, bs, cfg.num_kv_heads, cfg.head_dim)
+    k_pool = rng.normal(size=pool_shape).astype(np.float32)
+    v_pool = rng.normal(size=pool_shape).astype(np.float32)
+    ids = rng.permutation(B * T).reshape(B, T).astype(np.int32)
+    ids[3] = -1                                   # a dead lane
+    lengths = np.asarray([0, 5, T * bs - 1, 6], np.int32)
+    x = jnp.asarray(rng.normal(size=(B, 1, cfg.d_model)).astype(np.float32))
+    pos = jnp.asarray(lengths)
+
+    for layer in range(n_layers):
+        cache = {"k": jnp.asarray(k_pool), "v": jnp.asarray(v_pool),
+                 "block_tables": jnp.asarray(ids),
+                 "length": jnp.asarray(lengths), "layer": jnp.int32(layer)}
+        y, new = L.attention_decode(p, cfg, x, cache, pos)
+        alone = {"k": jnp.asarray(k_pool[layer:layer + 1]),
+                 "v": jnp.asarray(v_pool[layer:layer + 1]),
+                 "block_tables": jnp.asarray(ids),
+                 "length": jnp.asarray(lengths), "layer": jnp.int32(0)}
+        y_alone, new_alone = L.attention_decode(p, cfg, x, alone, pos)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y_alone))
+        np.testing.assert_array_equal(np.asarray(new["length"]), lengths + 1)
+
+        written = np.zeros(pool_shape[:3], bool)
+        for b, n in enumerate(lengths):
+            page = ids[b, n // bs]
+            written[layer, page if page >= 0 else trash, n % bs] = True
+        for name, pool in (("k", k_pool), ("v", v_pool)):
+            got = np.asarray(new[name])
+            np.testing.assert_array_equal(got[~written], pool[~written])
+            np.testing.assert_array_equal(
+                got[layer], np.asarray(new_alone[name])[0])
+            assert not np.array_equal(got[written], pool[written])
 
 
 def test_page_fetches_stay_in_the_pool():
@@ -370,6 +454,28 @@ def test_paged_windowed_arch_parity():
     batched = _drive(cfg, params, reqs, 1, "contiguous")
     paged = _drive(cfg, params, reqs, 1, "paged", block_size=4,
                    shuffle_seed=5)
+    assert batched == paged
+
+
+def test_paged_hybrid_arch_parity():
+    """A hybrid stack: attention runs of two layers between recurrent
+    runs, each attention run carrying its own stacked pools through its
+    layer loop."""
+    cfg, _ = _cfg_params("recurrentgemma-2b")
+    cfg = dataclasses.replace(cfg, num_layers=6,
+                              block_pattern=("rglru", "attn", "attn"))
+    assert blocks.runs(cfg) == [("rglru", 1), ("attn", 2)] * 2
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(9)
+    reqs = [Request(rid=i, client_id=0,
+                    prompt=rng.integers(0, cfg.vocab_size,
+                                        int(rng.integers(2, 9))
+                                        ).astype(np.int32),
+                    max_new_tokens=int(rng.integers(2, 7)))
+            for i in range(4)]
+    batched = _drive(cfg, params, reqs, 1, "contiguous")
+    paged = _drive(cfg, params, reqs, 1, "paged", block_size=4,
+                   shuffle_seed=9)
     assert batched == paged
 
 
