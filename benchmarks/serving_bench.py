@@ -13,11 +13,7 @@ tests/test_serving_engine.py):
     cluster replicas (``continuous``);
   * hot-swap cost: per-replica stall in ms with requests in flight
     (``continuous.swap``), in-flight count surviving the swap, and the
-    checkpoint-manifest-on-disk -> adoption latency (arrival-driven swap);
-  * paged KV arena vs contiguous slots head-to-head (``paged_kv``): the
-    over-capacity request paging admits and contiguous turns away
-    (``admitted_delta``), saturated throughput ratio, per-occupancy step
-    walls for both layouts.
+    checkpoint-manifest-on-disk -> adoption latency (arrival-driven swap).
 
 Output: ``BENCH_serving.json``.
 
@@ -34,13 +30,12 @@ import time
 from repro.launch.serve_fl import run_serving_pipeline
 
 SCHEMA_KEYS = ("meta", "federation", "continuous", "saturated", "oracle",
-               "occupancy_sweep", "paged_kv", "throughput_speedup")
+               "throughput_speedup")
 
 
 def check_schema(report: dict) -> None:
     for k in SCHEMA_KEYS:
         assert k in report, f"missing report key: {k}"
-    assert "kv_layout" in report["meta"]
     for k in ("tokens_per_s", "p50_ms", "p99_ms", "swap", "rejected"):
         assert k in report["continuous"], f"missing continuous key: {k}"
     swap = report["continuous"]["swap"]
@@ -56,50 +51,14 @@ def check_schema(report: dict) -> None:
     assert report["saturated"]["tokens_per_s"] > 0
     assert report["oracle"]["tokens_per_s"] > 0
     # the trace carries two poison requests by construction: rid 10_000
-    # (> per-slot capacity) is ADMITTED under the default paged layout,
-    # while rid 10_001 (> the whole pool) must still be rejected
-    # gracefully, not crash the driver loop
+    # (> per-slot capacity) is ADMITTED by the shared page pool, while
+    # rid 10_001 (> the whole pool) must still be rejected gracefully,
+    # not crash the driver loop
     assert report["continuous"]["rejected"] >= 1
-    if report["meta"]["kv_layout"] == "paged":
-        assert 10_000 not in report["continuous"]["rejected_rids"], (
-            "paged serving must admit the over-per-slot-capacity request"
-        )
-        assert 10_001 in report["continuous"]["rejected_rids"]
-    # ragged batched vs vmapped occupancy sweep (ISSUE 9 acceptance)
-    sweep = report["occupancy_sweep"]
-    for k in ("arch", "num_slots", "capacity", "per_occupancy",
-              "saturated_speedup", "batched_monotonic"):
-        assert k in sweep, f"missing occupancy_sweep key: {k}"
-    assert len(sweep["per_occupancy"]) == sweep["num_slots"]
-    for row in sweep["per_occupancy"]:
-        for k in ("occupancy", "batched_step_ms", "vmap_step_ms"):
-            assert k in row, f"missing per_occupancy key: {k}"
-    assert sweep["batched_monotonic"], (
-        "batched per-step wall grows as occupancy drops — dead lanes are "
-        "costing attention work again"
+    assert 10_000 not in report["continuous"]["rejected_rids"], (
+        "the page pool must admit the over-per-slot-capacity request"
     )
-    assert sweep["saturated_speedup"] >= 1.5, (
-        f"ragged batched step only {sweep['saturated_speedup']}x the "
-        "vmapped step at full occupancy (acceptance: >= 1.5x)"
-    )
-    # paged KV arena head-to-head (ISSUE 10 acceptance)
-    paged = report["paged_kv"]
-    for k in ("arch", "block_size", "pool_blocks", "contiguous", "paged",
-              "admitted_delta", "over_capacity_admits", "throughput_ratio",
-              "per_occupancy"):
-        assert k in paged, f"missing paged_kv key: {k}"
-    for row in paged["per_occupancy"]:
-        for k in ("occupancy", "contiguous_step_ms", "paged_step_ms"):
-            assert k in row, f"missing paged per_occupancy key: {k}"
-    assert paged["admitted_delta"] >= 1, (
-        "paging must admit at least one request contiguous slots reject"
-    )
-    assert paged["over_capacity_admits"] >= 1
-    assert paged["throughput_ratio"] >= 0.9, (
-        f"paged saturated throughput only {paged['throughput_ratio']}x "
-        "contiguous (acceptance: >= 0.9x) — block-table indirection is "
-        "taxing the fused step"
-    )
+    assert 10_001 in report["continuous"]["rejected_rids"]
 
 
 def run(smoke: bool = False, out: str = "BENCH_serving.json",
@@ -125,10 +84,6 @@ def run(smoke: bool = False, out: str = "BENCH_serving.json",
           f"swap stall max={c['swap']['max_stall_ms']}ms "
           f"inflight={c['swap']['inflight_before']} "
           f"adopt={c['swap']['ckpt_to_adoption_ms']}ms")
-    p = report["paged_kv"]
-    print(f"  paged_kv  : ratio={p['throughput_ratio']}x "
-          f"admitted_delta={p['admitted_delta']} "
-          f"(bs={p['block_size']}, pool={p['pool_blocks']} blocks)")
     return report
 
 

@@ -93,14 +93,11 @@ class ModelConfig:
     # forward stays on the differentiable jnp implementations). Unknown
     # values raise, never a silent fallback.
     prefill_backend: str = "auto"
-    # serving KV-cache layout (models/layers.attention_decode + the serving
-    # engine arena):
-    #   "contiguous" — dense per-row (B, capacity) cache axis (the in-tree
-    #                  parity oracle)
-    #   "paged"      — global pool of kv_block_size-position blocks + a
-    #                  per-row block table; row capacity is free-block
-    #                  accounting, not a per-slot constant
-    kv_layout: str = "contiguous"
+    # serving KV-cache layout: "paged" (a global pool of
+    # kv_block_size-position blocks + a per-row block table) is the only
+    # one. The serving engine reads nothing here; the field stays because
+    # bench/drivers/serve.py sets it.
+    kv_layout: str = "paged"
     # paged-arena page size: cache positions per KV block
     kv_block_size: int = 16
 

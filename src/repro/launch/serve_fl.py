@@ -125,8 +125,7 @@ def build_replicas(ckpt: MergeCheckpoint, template, cfg, num_clients: int,
     primed with the checkpoint's merge plan. ``warm=True`` pre-compiles
     the swap-adoption program per engine (a same-weights swap), so the
     first measured hot-swap times the transfer, not XLA. Extra
-    ``engine_kwargs`` (kv_layout, block_size, ...) pass through to every
-    engine."""
+    ``engine_kwargs`` (block_size, ...) pass through to every engine."""
     router = ClusterRouter(num_clients)
     router.update(ckpt.groups)
     engines = {
@@ -239,82 +238,6 @@ def serve_trace(
     return out
 
 
-def occupancy_sweep(params, cfg, num_slots: int = 8, capacity: int = 256,
-                    prompt_len: int = 8, steps: int = 24,
-                    arch: Optional[str] = None) -> dict:
-    """Per-occupancy fused decode-step wall, ragged batched vs vmapped.
-
-    For each occupancy 1..num_slots: admit that many requests into a fresh
-    engine and time ``steps`` fused decode steps. Run once per
-    ``fused_mode``. Every (occupancy bucket, depth bucket) program is
-    compiled by a throwaway engine driven through the same trajectory
-    first, so the timed pass measures steps, not XLA.
-
-    The two acceptance numbers (ISSUE 9): ``saturated_speedup`` =
-    vmap / batched per-step wall at full occupancy (the vmapped step burns
-    full-capacity attention on every lane; the ragged step only touches
-    the live (rows, depth) bucket), and ``batched_monotonic`` — batched
-    per-step wall must not *increase* as occupancy drops (dead lanes no
-    longer cost attention work)."""
-    arch = arch or cfg.name
-    max_new = steps + 4
-    assert prompt_len + max_new <= capacity, "sweep must fit in capacity"
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, cfg.vocab_size, prompt_len).astype(np.int32)
-               for _ in range(num_slots)]
-
-    def mk_reqs():
-        return [Request(rid=i, client_id=0, prompt=p, max_new_tokens=max_new)
-                for i, p in enumerate(prompts)]
-
-    def run(mode: str, occ: int) -> float:
-        eng = ServeEngine(params, cfg, num_slots=num_slots,
-                          capacity=capacity, fused_mode=mode)
-        for r in mk_reqs()[:occ]:
-            eng.try_admit(r)
-        for _ in range(2):  # settle past the first depth-bucket boundary
-            eng.step()
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            eng.step()
-        wall = time.perf_counter() - t0
-        return 1e3 * wall / steps
-
-    rows = []
-    for occ in range(1, num_slots + 1):
-        row = {"occupancy": occ}
-        for mode in ("batched", "vmap"):
-            run(mode, occ)  # compile pass: same trajectory, throwaway
-            # min-wall over repeats for batched: the monotonicity
-            # acceptance compares ~4 ms steps across occupancies, where a
-            # single scheduler hiccup in one 24-step sample trips the
-            # 1.25x tolerance; the minimum converges on the noise-free
-            # step floor (vmap steps are ~40x longer — one sample is
-            # already stable, and repeats would dominate the bench wall)
-            n = 3 if mode == "batched" else 1
-            row[f"{mode}_step_ms"] = round(
-                min(run(mode, occ) for _ in range(n)), 4)
-        rows.append(row)
-    sat = rows[-1]
-    batched_ms = [r["batched_step_ms"] for r in rows]
-    return {
-        "arch": arch,
-        "num_slots": num_slots,
-        "capacity": capacity,
-        "prompt_len": prompt_len,
-        "steps_timed": steps,
-        "per_occupancy": rows,
-        "saturated_speedup": round(
-            sat["vmap_step_ms"] / sat["batched_step_ms"], 3
-        ),
-        # dead lanes must not cost work: low occupancy no slower than full
-        "batched_monotonic": bool(
-            all(batched_ms[i] <= batched_ms[-1] * 1.25
-                for i in range(len(batched_ms)))
-        ),
-    }
-
-
 def saturated_throughput(params, cfg, requests: List[Request],
                          num_slots: int = 8, capacity: int = 64,
                          **engine_kwargs) -> dict:
@@ -322,9 +245,9 @@ def saturated_throughput(params, cfg, requests: List[Request],
     request is already queued at t=0 (offered load >> capacity), so slots
     stay full and tokens/sec measures the fused step, not the arrival
     process — the number to compare against ``sequential_oracle``. Extra
-    ``engine_kwargs`` (kv_layout, block_size, ...) pass through; a paged
-    engine may return None from try_admit on pool exhaustion, which just
-    holds the request at the head of the queue until an eviction."""
+    ``engine_kwargs`` (block_size, ...) pass through; the engine may
+    return None from try_admit on pool exhaustion, which just holds the
+    request at the head of the queue until an eviction."""
     eng = ServeEngine(params, cfg, num_slots=num_slots, capacity=capacity,
                       **engine_kwargs)
     for L in sorted({len(r.prompt) for r in requests}):
@@ -339,7 +262,7 @@ def saturated_throughput(params, cfg, requests: List[Request],
     while queue or eng.num_active:
         while queue and eng.free_slots():
             a = eng.try_admit(queue[0])
-            if a is None:  # paged pool exhausted: wait for an eviction
+            if a is None:  # page pool exhausted: wait for an eviction
                 break
             queue.pop(0)
             if a.done:
@@ -359,108 +282,6 @@ def saturated_throughput(params, cfg, requests: List[Request],
         "rejected": eng.rejects,
         "admitted": done - eng.rejects,
         "over_capacity_admits": eng.over_capacity_admits,
-    }
-
-
-def paged_kv_bench(num_slots: int = 4, capacity: int = 32,
-                   block_size: int = 8, steps: int = 8,
-                   arch: str = "qwen3-1.7b", seed: int = 0) -> dict:
-    """Paged-vs-contiguous serving head-to-head on a real-KV attention
-    arch (iso-memory: the page pool holds exactly num_slots * capacity
-    positions). Three acceptance numbers (ISSUE 10):
-
-      * ``admitted_delta`` >= 1 — a probe trace carries one request with
-        prompt + max_new > capacity; contiguous must reject it, paged must
-        serve it out of the shared pool (``over_capacity_admits``).
-      * ``throughput_ratio`` = paged / contiguous saturated tokens/sec
-        >= 0.9 on an IDENTICAL probe-free workload (warm-compiled both
-        sides) — block-table indirection must not tax the fused step.
-      * ``per_occupancy`` step walls for both layouts.
-    """
-    cfg = serve_config(arch)
-    params = M.init_params(jax.random.PRNGKey(2), cfg)
-    rng = np.random.default_rng(seed)
-    prompt_len = 8
-    max_new = 2 * steps + 4
-    assert prompt_len + max_new <= capacity
-    reqs = [Request(rid=i, client_id=0,
-                    prompt=rng.integers(0, cfg.vocab_size,
-                                        prompt_len).astype(np.int32),
-                    max_new_tokens=max_new)
-            for i in range(3 * num_slots)]
-    # the over-capacity probe: impossible contiguously, pageable
-    over = Request(rid=10_000, client_id=0,
-                   prompt=rng.integers(0, cfg.vocab_size,
-                                       prompt_len).astype(np.int32),
-                   max_new_tokens=capacity + prompt_len)
-
-    def run(layout: str, trace: List[Request]) -> dict:
-        kw = {"kv_layout": layout}
-        if layout == "paged":
-            kw["block_size"] = block_size
-        return saturated_throughput(params, cfg, trace, num_slots=num_slots,
-                                    capacity=capacity, **kw)
-
-    # throughput: IDENTICAL probe-free workload for both layouts (both
-    # admit every request), first pass per layout throwaway so the timed
-    # passes hit only cached programs, then INTERLEAVED timed pairs with
-    # the best run kept per layout. Best-of-N is a min-wall estimator: it
-    # converges on each layout's noise-free floor, so the ratio isolates
-    # the block-table indirection cost, not compile order, workload mix,
-    # or a scheduler dip that happens to land on one layout's runs
-    run("contiguous", reqs)
-    run("paged", reqs)
-    con_runs, pag_runs = [], []
-    for _ in range(9):
-        con_runs.append(run("contiguous", reqs))
-        pag_runs.append(run("paged", reqs))
-    con = max(con_runs, key=lambda r: r["tokens_per_s"])
-    pag = max(pag_runs, key=lambda r: r["tokens_per_s"])
-
-    # admission: the probe-carrying trace, where the layouts diverge —
-    # contiguous must turn rid 10_000 away, paged must serve it
-    probe_trace = reqs[:num_slots] + [over] + reqs[num_slots:]
-    con_probe = run("contiguous", probe_trace)
-    pag_probe = run("paged", probe_trace)
-
-    def step_ms(layout: str, occ: int) -> float:
-        kw = {"kv_layout": layout}
-        if layout == "paged":
-            kw["block_size"] = block_size
-        eng = ServeEngine(params, cfg, num_slots=num_slots,
-                          capacity=capacity, **kw)
-        for r in reqs[:occ]:
-            eng.try_admit(r)
-        for _ in range(2):  # settle past the first depth-bucket boundary
-            eng.step()
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            eng.step()
-        return 1e3 * (time.perf_counter() - t0) / steps
-
-    per_occ = []
-    for occ in sorted({1, max(num_slots // 2, 1), num_slots}):
-        row = {"occupancy": occ}
-        for layout in ("contiguous", "paged"):
-            step_ms(layout, occ)  # compile pass: same trajectory, throwaway
-            row[f"{layout}_step_ms"] = round(step_ms(layout, occ), 4)
-        per_occ.append(row)
-
-    return {
-        "arch": arch,
-        "num_slots": num_slots,
-        "capacity": capacity,
-        "block_size": block_size,
-        "pool_blocks": -(-num_slots * capacity // block_size),
-        "contiguous": con,
-        "paged": pag,
-        # the over-capacity request paged serves and contiguous turns away
-        "admitted_delta": pag_probe["admitted"] - con_probe["admitted"],
-        "over_capacity_admits": pag_probe["over_capacity_admits"],
-        "throughput_ratio": round(
-            pag["tokens_per_s"] / con["tokens_per_s"], 3
-        ),
-        "per_occupancy": per_occ,
     }
 
 
@@ -499,13 +320,10 @@ def run_serving_pipeline(
     ckpt_dir: str = "ckpts_serving",
     seed: int = 0,
     pipeline: str = "engine",
-    kv_layout: str = "paged",
     kv_block_size: int = 8,
 ) -> dict:
     """The full federation -> serving pipeline; returns the report dict
-    (benchmarks/serving_bench.py writes it to BENCH_serving.json).
-    Serving benches default to the paged KV arena; the contiguous layout
-    stays available as the in-tree parity oracle (``kv_layout``)."""
+    (benchmarks/serving_bench.py writes it to BENCH_serving.json)."""
     cfg = serve_config()
     spec = fl_spec(seed=seed, pipeline=pipeline, smoke=smoke)
     n_req = num_requests or (12 if smoke else 64)
@@ -523,12 +341,9 @@ def run_serving_pipeline(
         )
 
     template = M.init_params(jax.random.PRNGKey(0), cfg)
-    engine_kwargs = {"kv_layout": kv_layout}
-    if kv_layout == "paged":
-        engine_kwargs["block_size"] = kv_block_size
     replicas = build_replicas(ckpts[0], template, cfg, spec.num_clients,
                               num_slots=num_slots, capacity=capacity,
-                              **engine_kwargs)
+                              block_size=kv_block_size)
     gen = poisson_requests if traffic == "poisson" else diurnal_requests
     kw = dict(num_clients=spec.num_clients, vocab_size=cfg.vocab_size,
               max_new_tokens=8, seed=seed)
@@ -537,15 +352,14 @@ def run_serving_pipeline(
     else:
         requests = gen(n_req, rate, peak_factor=3.0, period_s=2.0, **kw)
     mid = requests[len(requests) // 2]
-    # the old per-slot poison (> capacity): contiguous rejects it, the
-    # paged pool ADMITS it — the tentpole's visible capacity win
+    # longer than one slot's capacity: the shared page pool admits it
     requests = requests + [Request(
         rid=10_000, client_id=mid.client_id,
         prompt=np.zeros(4, np.int32), max_new_tokens=capacity + 1,
         arrival=mid.arrival,
     )]
-    # the super-poison (> the whole pool): impossible under any layout —
-    # exercises the graceful-reject path end to end even with paging on
+    # the super-poison (> the whole pool): exercises the graceful-reject
+    # path end to end
     requests = requests + [Request(
         rid=10_001, client_id=mid.client_id,
         prompt=np.zeros(4, np.int32),
@@ -562,43 +376,14 @@ def run_serving_pipeline(
     final_global = load_model(ckpts[-1].global_path, template)
     saturated = saturated_throughput(final_global, cfg, requests,
                                      num_slots=num_slots, capacity=capacity,
-                                     **engine_kwargs)
+                                     block_size=kv_block_size)
     oracle = sequential_oracle(final_global, cfg, requests,
                                capacity=capacity)
-    # ragged-vs-vmapped occupancy sweep on an *attention* arch (the vmapped
-    # step burns full-capacity attention per lane — the number the ragged
-    # batched path is built to beat)
-    sweep_arch = "qwen3-1.7b"
-    sweep_cfg = serve_config(sweep_arch)
-    sweep_params = M.init_params(jax.random.PRNGKey(1), sweep_cfg)
-    sweep = occupancy_sweep(
-        sweep_params, sweep_cfg,
-        num_slots=4 if smoke else max(num_slots, 8),
-        capacity=256 if smoke else 1024,
-        steps=8 if smoke else 24,
-        arch=sweep_arch,
-    )
-    # paged-vs-contiguous head-to-head on a real-KV attention arch (the
-    # serve arch is recurrent — its paged win is admission accounting, not
-    # cache paging, so the KV numbers come from qwen3)
-    # capacity 48: deep enough that rows cross several depth buckets, but
-    # the jnp CPU fallback's page-gather tax (which grows with attended
-    # depth — the per_occupancy rows record it) stays within the 0.9x
-    # acceptance floor; the Pallas path reads pages by DMA and pays none
-    paged_kv = paged_kv_bench(
-        num_slots=4,
-        capacity=32 if smoke else 48,
-        block_size=kv_block_size,
-        steps=10 if smoke else 12,
-        arch=sweep_arch,
-        seed=seed,
-    )
     report = {
         "meta": {
             "arch": cfg.name,
             "num_slots": num_slots,
             "capacity": capacity,
-            "kv_layout": kv_layout,
             "kv_block_size": kv_block_size,
             "traffic": traffic,
             "rate_req_s": rate,
@@ -616,8 +401,6 @@ def run_serving_pipeline(
         "continuous": continuous,
         "saturated": saturated,
         "oracle": oracle,
-        "occupancy_sweep": sweep,
-        "paged_kv": paged_kv,
         # peak continuous-batching decode rate over the no-batching oracle
         # (the open-loop trace's tokens/sec is arrival-gated, so the
         # saturated engine is the honest throughput comparison)
@@ -640,8 +423,6 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="ckpts_serving")
     ap.add_argument("--pipeline", choices=("engine", "device"),
                     default="engine")
-    ap.add_argument("--kv-layout", choices=("contiguous", "paged"),
-                    default="paged")
     ap.add_argument("--kv-block-size", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None,
@@ -652,7 +433,7 @@ def main() -> None:
         smoke=args.smoke, num_slots=args.num_slots, capacity=args.capacity,
         num_requests=args.requests, rate=args.rate, traffic=args.traffic,
         ckpt_dir=args.ckpt_dir, seed=args.seed, pipeline=args.pipeline,
-        kv_layout=args.kv_layout, kv_block_size=args.kv_block_size,
+        kv_block_size=args.kv_block_size,
     )
     print(json.dumps(report, indent=1))
     if args.out:

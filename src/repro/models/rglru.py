@@ -76,10 +76,10 @@ def rglru_fwd(params, cfg, x, positions=None, return_state: bool = False):
     _, h = jax.lax.associative_scan(combine, (a, b), axis=1)
     y = (h.astype(x.dtype) * gate) @ params["w_out"]
     if return_state:
-        state = {
-            "h": h[:, -1, :],
-            "conv": (x @ params["w_in"])[:, -(_CONV_W - 1):, :],
-        }
+        # the conv window the next token sees; a prompt shorter than it
+        # leaves the conv's zero padding in front
+        u = jnp.pad(x @ params["w_in"], ((0, 0), (_CONV_W - 1, 0), (0, 0)))
+        state = {"h": h[:, -1, :], "conv": u[:, -(_CONV_W - 1):, :]}
         return y, state
     return y
 

@@ -1,5 +1,6 @@
-"""Paged KV arena (ISSUE 10): block-table attention from the kernel to the
-serving engine must be token-for-token identical to the contiguous layouts.
+"""Paged KV arena: block-table attention from the kernel to the serving
+engine must be token-for-token identical to the contiguous cache of the
+sequential ``generate`` oracle.
 
 Four layers of checks:
 
@@ -9,11 +10,11 @@ Four layers of checks:
     (pool + fragmented block table) matches the contiguous ring cache.
   * Kernel level: the block-table Pallas kernel (interpret mode) matches
     the pure-jnp paged reference on fragmented tables.
-  * Engine level: the paged engine == contiguous batched == sequential
-    ``generate`` oracle for arbitrary request mixes, block sizes and
-    fragmented free lists — including across a merge-round hot swap, with
-    eviction poisoning on, and through pool exhaustion + over-capacity
-    admission (the capacity win contiguous slots cannot express).
+  * Engine level: the paged engine == the sequential ``generate`` oracle
+    for arbitrary request mixes, block sizes and fragmented free lists, on
+    attention, recurrent and hybrid archs, including across a merge-round
+    hot swap, with eviction poisoning on, and through pool exhaustion and
+    admission of a request longer than one slot.
 
 Plus the checkpoint-arrival machinery: manifest round-trip through
 ``CheckpointWatcher`` and the checkpoint-to-adoption latency stamp.
@@ -51,7 +52,7 @@ from repro.serving.fl_model import serve_config
 from repro.serving.traffic import Request
 
 CAP = 16
-ARCHS = ("qwen3-1.7b", "xlstm-125m")
+ARCHS = ("qwen3-1.7b", "xlstm-125m", "recurrentgemma-2b")
 BLOCK_SIZES = (1, 4, 16)
 
 
@@ -361,21 +362,19 @@ def test_page_fetches_stay_in_the_pool():
 
 
 # ---------------------------------------------------------------------------
-# engine level: paged == contiguous batched == generate oracle
+# engine level: paged engine == generate oracle
 # ---------------------------------------------------------------------------
 
 
-def _drive(cfg, params, reqs, stagger, kv_layout, block_size=4,
-           shuffle_seed=None, debug_poison=False):
+def _drive(cfg, params, reqs, stagger, block_size=4, shuffle_seed=None,
+           debug_poison=False):
     """Admit ``reqs`` into a 4-slot engine as slots free up and collect
-    every request's token stream. ``shuffle_seed`` pre-fragments the paged
+    every request's token stream. ``shuffle_seed`` pre-fragments the
     allocator's free list so block tables are never contiguous."""
-    kw = {}
-    if kv_layout == "paged":
-        kw = {"kv_layout": "paged", "block_size": block_size,
-              "debug_poison_evictions": debug_poison}
-    eng = ServeEngine(params, cfg, num_slots=4, capacity=CAP, **kw)
-    if kv_layout == "paged" and shuffle_seed is not None:
+    eng = ServeEngine(params, cfg, num_slots=4, capacity=CAP,
+                      block_size=block_size,
+                      debug_poison_evictions=debug_poison)
+    if shuffle_seed is not None:
         np.random.default_rng(shuffle_seed).shuffle(eng.allocator._free)
     queue = list(reqs)
     out = {}
@@ -384,7 +383,7 @@ def _drive(cfg, params, reqs, stagger, kv_layout, block_size=4,
         while queue and eng.free_slots():
             a = eng.try_admit(queue[0])
             if a is None:
-                break  # paged pool exhausted: wait for an eviction
+                break  # page pool exhausted: wait for an eviction
             queue.pop(0)
             if a.done:
                 out[a.request.rid] = a.tokens
@@ -397,11 +396,29 @@ def _drive(cfg, params, reqs, stagger, kv_layout, block_size=4,
         admit_all()
         for fin in eng.step():
             out[fin.request.rid] = fin.tokens
-    if kv_layout == "paged":
-        # every page back on the free list, every promise returned
-        assert eng.allocator.free_blocks() == eng.pool_blocks
-        assert eng.allocator.reserved == 0
+    # every page back on the free list, every promise returned
+    assert eng.allocator.free_blocks() == eng.pool_blocks
+    assert eng.allocator.reserved == 0
     return out
+
+
+def _random_requests(cfg, rng, n):
+    return [Request(rid=i, client_id=0,
+                    prompt=rng.integers(0, cfg.vocab_size,
+                                        int(rng.integers(2, 9))
+                                        ).astype(np.int32),
+                    max_new_tokens=int(rng.integers(2, 7)))
+            for i in range(n)]
+
+
+def _assert_matches_oracle(params, cfg, reqs, got):
+    """Every request's tokens equal the sequential ``generate`` oracle's."""
+    for r in reqs:
+        toks, _ = generate(params, cfg, {"tokens": r.prompt[None]},
+                           max_new_tokens=r.max_new_tokens, capacity=CAP)
+        assert got[r.rid] == list(np.asarray(toks[0])), (
+            f"rid {r.rid} diverges from the sequential oracle"
+        )
 
 
 @settings(max_examples=6, deadline=None)
@@ -412,10 +429,10 @@ def _drive(cfg, params, reqs, stagger, kv_layout, block_size=4,
     n_req=st.integers(1, 6),
     stagger=st.integers(0, 3),
 )
-def test_paged_equals_batched_equals_oracle(arch_i, bs_i, seed, n_req,
-                                            stagger):
-    """The property: for arbitrary request mixes, block sizes and
-    fragmented free lists, the paged engine, the contiguous batched
+def test_paged_engine_equals_oracle(arch_i, bs_i, seed, n_req, stagger):
+    """The property: for arbitrary request mixes (prompt depth, budget,
+    admission interleaving, which together make arbitrary occupancy masks
+    and per-row depths), block sizes and fragmented free lists, the paged
     engine and the sequential oracle emit identical tokens per request."""
     cfg, params = _cfg_params(ARCHS[arch_i])
     rng = np.random.default_rng(seed)
@@ -426,35 +443,18 @@ def test_paged_equals_batched_equals_oracle(arch_i, bs_i, seed, n_req,
         prompt = rng.integers(0, cfg.vocab_size, L_p).astype(np.int32)
         reqs.append(Request(rid=i, client_id=0, prompt=prompt,
                             max_new_tokens=max_new))
-
-    batched = _drive(cfg, params, reqs, stagger, "contiguous")
-    paged = _drive(cfg, params, reqs, stagger, "paged",
+    paged = _drive(cfg, params, reqs, stagger,
                    block_size=BLOCK_SIZES[bs_i], shuffle_seed=seed)
-    assert batched == paged
-    for r in reqs:
-        toks, _ = generate(params, cfg, {"tokens": r.prompt[None]},
-                           max_new_tokens=r.max_new_tokens, capacity=CAP)
-        got = paged[r.rid]
-        assert got == list(np.asarray(toks[0][:len(got)])), (
-            f"rid {r.rid} diverges from the sequential oracle"
-        )
+    _assert_matches_oracle(params, cfg, reqs, paged)
 
 
 def test_paged_windowed_arch_parity():
     cfg, _ = _cfg_params("qwen3-1.7b")
     cfg = dataclasses.replace(cfg, window_size=8)
     params = M.init_params(jax.random.PRNGKey(0), cfg)
-    rng = np.random.default_rng(5)
-    reqs = [Request(rid=i, client_id=0,
-                    prompt=rng.integers(0, cfg.vocab_size,
-                                        int(rng.integers(2, 9))
-                                        ).astype(np.int32),
-                    max_new_tokens=int(rng.integers(2, 7)))
-            for i in range(5)]
-    batched = _drive(cfg, params, reqs, 1, "contiguous")
-    paged = _drive(cfg, params, reqs, 1, "paged", block_size=4,
-                   shuffle_seed=5)
-    assert batched == paged
+    reqs = _random_requests(cfg, np.random.default_rng(5), 5)
+    paged = _drive(cfg, params, reqs, 1, block_size=4, shuffle_seed=5)
+    _assert_matches_oracle(params, cfg, reqs, paged)
 
 
 def test_paged_hybrid_arch_parity():
@@ -466,17 +466,9 @@ def test_paged_hybrid_arch_parity():
                               block_pattern=("rglru", "attn", "attn"))
     assert blocks.runs(cfg) == [("rglru", 1), ("attn", 2)] * 2
     params = M.init_params(jax.random.PRNGKey(0), cfg)
-    rng = np.random.default_rng(9)
-    reqs = [Request(rid=i, client_id=0,
-                    prompt=rng.integers(0, cfg.vocab_size,
-                                        int(rng.integers(2, 9))
-                                        ).astype(np.int32),
-                    max_new_tokens=int(rng.integers(2, 7)))
-            for i in range(4)]
-    batched = _drive(cfg, params, reqs, 1, "contiguous")
-    paged = _drive(cfg, params, reqs, 1, "paged", block_size=4,
-                   shuffle_seed=9)
-    assert batched == paged
+    reqs = _random_requests(cfg, np.random.default_rng(9), 4)
+    paged = _drive(cfg, params, reqs, 1, block_size=4, shuffle_seed=9)
+    _assert_matches_oracle(params, cfg, reqs, paged)
 
 
 def test_paged_poison_evictions_invisible():
@@ -491,68 +483,62 @@ def test_paged_poison_evictions_invisible():
                                         ).astype(np.int32),
                     max_new_tokens=int(rng.integers(1, 7)))
             for i in range(8)]
-    plain = _drive(cfg, params, reqs, 2, "paged", block_size=4,
-                   shuffle_seed=1)
-    poisoned = _drive(cfg, params, reqs, 2, "paged", block_size=4,
-                      shuffle_seed=1, debug_poison=True)
+    plain = _drive(cfg, params, reqs, 2, block_size=4, shuffle_seed=1)
+    poisoned = _drive(cfg, params, reqs, 2, block_size=4, shuffle_seed=1,
+                      debug_poison=True)
     assert plain == poisoned
 
 
-def test_paged_parity_across_hot_swap():
-    """Mixed depths + a weight hot-swap mid-flight: paged and contiguous
-    agree token-for-token through the swap, and a post-swap admission
-    matches the sequential oracle on the new weights."""
-    cfg, params = _cfg_params("qwen3-1.7b")
+@pytest.mark.parametrize("arch", ARCHS)
+def test_paged_parity_across_hot_swap(arch):
+    """Rows at mixed depths + a weight hot-swap mid-flight: the rows in
+    flight run to their budgets, and a post-swap admission matches the
+    sequential oracle on the new weights end to end."""
+    cfg, params = _cfg_params(arch)
     p_new = M.init_params(jax.random.PRNGKey(9), cfg)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (3, 7, 5)]
-
-    def run(layout):
-        kw = ({"kv_layout": "paged", "block_size": 4}
-              if layout == "paged" else {})
-        eng = ServeEngine(params, cfg, num_slots=4, capacity=CAP, **kw)
-        a = eng.try_admit(Request(rid=0, client_id=0, prompt=prompts[0],
-                                  max_new_tokens=9))
-        eng.step()
-        eng.step()
-        b = eng.try_admit(Request(rid=1, client_id=0, prompt=prompts[1],
-                                  max_new_tokens=5))
-        eng.step()
-        eng.swap_params(p_new)  # mixed occupancy, mixed depths, swap
-        c = eng.try_admit(Request(rid=2, client_id=0, prompt=prompts[2],
-                                  max_new_tokens=4))
-        eng.run_to_completion()
-        assert len(a.tokens) == 9 and len(b.tokens) == 5
-        return [a.tokens, b.tokens, c.tokens]
-
-    assert run("contiguous") == run("paged")
+    eng = ServeEngine(params, cfg, num_slots=4, capacity=CAP, block_size=4)
+    a = eng.try_admit(Request(rid=0, client_id=0, prompt=prompts[0],
+                              max_new_tokens=9))
+    eng.step()
+    eng.step()  # rid 0 now 2 tokens deeper than rid 1 at admit
+    b = eng.try_admit(Request(rid=1, client_id=0, prompt=prompts[1],
+                              max_new_tokens=5))
+    eng.step()
+    # before the swap, both rows decode the old weights exactly
+    for r, prompt in ((a, prompts[0]), (b, prompts[1])):
+        toks, _ = generate(params, cfg, {"tokens": prompt[None]},
+                           max_new_tokens=r.request.max_new_tokens,
+                           capacity=CAP)
+        assert r.tokens == list(np.asarray(toks[0][:len(r.tokens)]))
+    eng.swap_params(p_new)  # mixed occupancy, mixed depths, swap
+    c = eng.try_admit(Request(rid=2, client_id=0, prompt=prompts[2],
+                              max_new_tokens=4))
+    eng.run_to_completion()
+    assert len(a.tokens) == 9 and len(b.tokens) == 5
     toks, _ = generate(p_new, cfg, {"tokens": prompts[2][None]},
                        max_new_tokens=4, capacity=CAP)
-    assert run("paged")[2] == list(np.asarray(toks[0]))
+    assert c.tokens == list(np.asarray(toks[0]))
 
 
 # ---------------------------------------------------------------------------
-# capacity semantics: over-capacity admission and pool exhaustion
+# capacity semantics: admission past one slot and pool exhaustion
 # ---------------------------------------------------------------------------
 
 
-def test_paged_admits_what_contiguous_rejects():
-    """prompt + max_new > capacity but <= num_slots * capacity: contiguous
-    hard-rejects, the paged pool serves it — token-for-token with the
-    sequential oracle at the pool-wide capacity."""
+def test_paged_admits_past_one_slot():
+    """prompt + max_new > capacity but <= num_slots * capacity: the shared
+    pool serves it, token-for-token with the sequential oracle at the
+    pool-wide capacity; past the whole pool it is rejected."""
     cfg, params = _cfg_params("qwen3-1.7b")
     rng = np.random.default_rng(2)
     big = Request(rid=99, client_id=0,
                   prompt=rng.integers(0, cfg.vocab_size, 6).astype(np.int32),
                   max_new_tokens=CAP + 4)  # 26 > 16, <= 64
 
-    con = ServeEngine(params, cfg, num_slots=4, capacity=CAP)
-    a = con.try_admit(big)
-    assert a is not None and a.rejected and con.rejects == 1
-
-    pag = ServeEngine(params, cfg, num_slots=4, capacity=CAP,
-                      kv_layout="paged", block_size=4)
+    pag = ServeEngine(params, cfg, num_slots=4, capacity=CAP, block_size=4)
     a = pag.try_admit(big)
     assert a is not None and not a.rejected
     pag.run_to_completion()
@@ -562,11 +548,11 @@ def test_paged_admits_what_contiguous_rejects():
                        max_new_tokens=CAP + 4, capacity=pag.max_row_len)
     assert a.tokens == list(np.asarray(toks[0]))
 
-    # beyond even the whole pool: uniform hard reject
+    # beyond even the whole pool: hard reject
     sup = Request(rid=100, client_id=0, prompt=big.prompt,
                   max_new_tokens=4 * CAP + 1)
     r = pag.try_admit(sup)
-    assert r is not None and r.rejected
+    assert r is not None and r.rejected and pag.rejects == 1
 
 
 def test_paged_pool_exhaustion_recovers():
@@ -576,7 +562,7 @@ def test_paged_pool_exhaustion_recovers():
     cfg, params = _cfg_params("qwen3-1.7b")
     rng = np.random.default_rng(4)
     eng = ServeEngine(params, cfg, num_slots=4, capacity=8,
-                      kv_layout="paged", block_size=4)  # pool: 8 pages
+                      block_size=4)  # pool: 8 pages
 
     def req(rid):
         return Request(rid=rid, client_id=0,
@@ -597,14 +583,12 @@ def test_paged_pool_exhaustion_recovers():
     assert eng.allocator.free_blocks() == eng.pool_blocks
 
 
-def test_paged_requires_batched_mode():
+def test_engine_rejects_other_layouts():
     cfg, params = _cfg_params("qwen3-1.7b")
-    with pytest.raises(ValueError):
-        ServeEngine(params, cfg, num_slots=2, capacity=8,
-                    kv_layout="paged", block_size=4, fused_mode="vmap")
-    with pytest.raises(ValueError):
-        ServeEngine(params, cfg, num_slots=2, capacity=8,
-                    kv_layout="bogus")
+    for layout in ("contiguous", "bogus"):
+        with pytest.raises(ValueError):
+            ServeEngine(params, cfg, num_slots=2, capacity=8,
+                        kv_layout=layout)
 
 
 # ---------------------------------------------------------------------------

@@ -1,44 +1,30 @@
-"""Ragged batched decode (ISSUE 9): the single batched ``decode_step``
-over per-row cache lengths must be token-for-token identical to the
-vmap-of-batch-1 step and the sequential ``generate`` oracle, for arbitrary
-occupancy masks and per-slot depths, on attention and recurrent archs —
-including across a merge-round hot swap.
+"""Ragged decode at the layer level: ``models/layers.attention_decode``
+with a per-row ``length`` vector, the contiguous cache that the sequential
+``launch.serve.generate`` oracle decodes through, must match running each
+row as its own batch-1 call (full and sliding-window caches).
 
-Layer-level: ``models/layers.attention_decode`` with a per-row ragged
-``length`` vector must be bit-identical to running each row as its own
-batch-1 call (full and sliding-window caches).
+The engine-level parity of the paged serving engine against that oracle
+is in tests/test_paged_kv.py.
 """
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from _hyp import given, settings, st
-from repro.launch.serve import generate
 from repro.models import layers as L
-from repro.models import model as M
-from repro.serving.engine import ServeEngine
 from repro.serving.fl_model import serve_config
-from repro.serving.traffic import Request
-
-CAP = 16
-ARCHS = ("qwen3-1.7b", "xlstm-125m", "recurrentgemma-2b")
-
-
-@functools.lru_cache(maxsize=4)
-def _cfg_params(arch: str):
-    cfg = serve_config(arch)
-    return cfg, M.init_params(jax.random.PRNGKey(0), cfg)
-
-
-# ---------------------------------------------------------------------------
-# layer level: ragged attention_decode == per-row batch-1 calls
-# ---------------------------------------------------------------------------
 
 
 def _ragged_attention_case(window: int, seed: int):
+    """One batch-5 call against five batch-1 calls in f32. The written
+    ``length`` must match exactly. ``y`` and the written ``k`` are compared
+    to 1e-5 absolute and relative: the two programs are different XLA
+    computations, whose reductions may run in another order. On the CPU
+    the largest difference read is 7.7e-7 in ``y`` (values up to 1.6)
+    and 1.7e-6 in ``k``, a few f32 ulps, so 1e-5 is about six times the
+    largest reading. A row that writes one slot off, or that drops its
+    mask, moves ``k`` or ``y`` by O(1)."""
     cfg = serve_config("qwen3-1.7b")
     if window:
         cfg = dataclasses.replace(cfg, window_size=window)
@@ -61,11 +47,13 @@ def _ragged_attention_case(window: int, seed: int):
     for b in range(B):
         row = {k: v[b:b + 1] for k, v in cache.items()}
         yb, nb = L.attention_decode(p, cfg, x[b:b + 1], row, pos[b:b + 1])
-        np.testing.assert_array_equal(np.asarray(y[b]), np.asarray(yb[0]))
+        np.testing.assert_allclose(np.asarray(y[b]), np.asarray(yb[0]),
+                                   atol=1e-5, rtol=1e-5)
         np.testing.assert_array_equal(
             np.asarray(new["length"][b]), np.asarray(nb["length"][0]))
-        np.testing.assert_array_equal(
-            np.asarray(new["k"][b]), np.asarray(nb["k"][0]))
+        np.testing.assert_allclose(np.asarray(new["k"][b]),
+                                   np.asarray(nb["k"][0]),
+                                   atol=1e-5, rtol=1e-5)
 
 
 def test_ragged_attention_decode_rowwise_full():
@@ -75,111 +63,3 @@ def test_ragged_attention_decode_rowwise_full():
 def test_ragged_attention_decode_rowwise_windowed():
     # window < cache depth: the ring-buffer path
     _ragged_attention_case(window=8, seed=1)
-
-
-# ---------------------------------------------------------------------------
-# engine level: batched == vmap == generate for arbitrary occupancy/depths
-# ---------------------------------------------------------------------------
-
-
-def _drive(mode: str, cfg, params, reqs, stagger: int):
-    """Admit ``reqs`` into a 4-slot engine as slots free up (the first
-    ``stagger`` steps run before any further admission) and collect every
-    request's token stream."""
-    eng = ServeEngine(params, cfg, num_slots=4, capacity=CAP,
-                      fused_mode=mode)
-    queue = list(reqs)
-    out = {}
-
-    def admit_all():
-        while queue and eng.free_slots():
-            a = eng.try_admit(queue.pop(0))
-            if a is None:
-                break
-            if a.done:
-                out[a.request.rid] = a.tokens
-
-    admit_all()
-    for _ in range(stagger):
-        for fin in eng.step():
-            out[fin.request.rid] = fin.tokens
-    while queue or eng.num_active:
-        admit_all()
-        for fin in eng.step():
-            out[fin.request.rid] = fin.tokens
-    return out
-
-
-@settings(max_examples=6, deadline=None)
-@given(
-    arch_i=st.integers(0, len(ARCHS) - 1),
-    seed=st.integers(0, 2**16),
-    n_req=st.integers(1, 6),
-    stagger=st.integers(0, 3),
-)
-def test_ragged_batched_equals_vmap_equals_oracle(arch_i, seed, n_req,
-                                                  stagger):
-    """The property: for arbitrary request mixes (prompt depth, budget,
-    admission interleaving — which together produce arbitrary occupancy
-    masks and per-slot depths), the ragged batched engine, the vmapped
-    engine and the sequential oracle emit identical tokens per request."""
-    cfg, params = _cfg_params(ARCHS[arch_i])
-    rng = np.random.default_rng(seed)
-    reqs = []
-    for i in range(n_req):
-        L_p = int(rng.integers(1, 9))
-        max_new = int(rng.integers(1, min(7, CAP - L_p + 1)))
-        prompt = rng.integers(0, cfg.vocab_size, L_p).astype(np.int32)
-        reqs.append(Request(rid=i, client_id=0, prompt=prompt,
-                            max_new_tokens=max_new))
-
-    batched = _drive("batched", cfg, params, reqs, stagger)
-    vmapped = _drive("vmap", cfg, params, reqs, stagger)
-    assert batched == vmapped
-    for r in reqs:
-        toks, _ = generate(params, cfg, {"tokens": r.prompt[None]},
-                           max_new_tokens=r.max_new_tokens, capacity=CAP)
-        got = batched[r.rid]
-        assert got == list(np.asarray(toks[0][:len(got)])), (
-            f"rid {r.rid} diverges from the sequential oracle"
-        )
-
-
-# ---------------------------------------------------------------------------
-# mixed occupancy across a merge-round hot swap
-# ---------------------------------------------------------------------------
-
-
-def test_mixed_occupancy_across_hot_swap():
-    """Slots at different depths + a hot swap mid-flight: both engine
-    modes agree token-for-token through the swap, survivors complete, and
-    a post-swap admission matches a fresh engine on the new weights."""
-    cfg, params = _cfg_params("qwen3-1.7b")
-    p_new = M.init_params(jax.random.PRNGKey(9), cfg)
-    rng = np.random.default_rng(3)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
-               for n in (3, 7, 5)]
-
-    def run(mode):
-        eng = ServeEngine(params, cfg, num_slots=4, capacity=CAP,
-                          fused_mode=mode)
-        a = eng.try_admit(Request(rid=0, client_id=0, prompt=prompts[0],
-                                  max_new_tokens=9))
-        eng.step()
-        eng.step()  # rid 0 now 2 tokens deeper than rid 1 at admit
-        b = eng.try_admit(Request(rid=1, client_id=0, prompt=prompts[1],
-                                  max_new_tokens=5))
-        eng.step()
-        eng.swap_params(p_new)  # mixed occupancy, mixed depths, swap
-        c = eng.try_admit(Request(rid=2, client_id=0, prompt=prompts[2],
-                                  max_new_tokens=4))
-        eng.run_to_completion()
-        assert len(a.tokens) == 9 and len(b.tokens) == 5
-        return [a.tokens, b.tokens, c.tokens]
-
-    batched, vmapped = run("batched"), run("vmap")
-    assert batched == vmapped
-    # the post-swap admission decodes the new weights end to end
-    toks, _ = generate(p_new, cfg, {"tokens": prompts[2][None]},
-                       max_new_tokens=4, capacity=CAP)
-    assert batched[2] == list(np.asarray(toks[0]))

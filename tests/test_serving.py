@@ -37,6 +37,24 @@ def test_decode_matches_forward(arch, rng):
     )
 
 
+@pytest.mark.parametrize("S", [1, 2])
+def test_decode_matches_forward_prompt_shorter_than_conv(S, rng):
+    """A prompt shorter than the RG-LRU's causal conv window: prefill hands
+    decode the window with the conv's zero padding in front."""
+    cfg = _cfg("recurrentgemma-2b")
+    params = init_params(rng, cfg)
+    toks = jax.random.randint(rng, (2, S + 1), 0, cfg.vocab_size)
+    logits_full, _ = forward(params, cfg, {"tokens": toks}, remat=False)
+    _, raw = prefill(params, cfg, {"tokens": toks[:, :S]})
+    states = states_from_prefill(cfg, raw, S, capacity=S + 8)
+    logits_dec, _ = decode_step(
+        params, cfg, states, toks[:, S], jnp.full((2,), S, jnp.int32)
+    )
+    np.testing.assert_allclose(
+        np.asarray(logits_dec), np.asarray(logits_full[:, -1]), atol=2e-4
+    )
+
+
 def test_multi_token_decode_consistency(rng):
     """Greedy generate agrees with repeated full forwards (teacher forcing
     its own outputs) for a dense model."""

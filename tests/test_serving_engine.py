@@ -151,7 +151,7 @@ def test_replica_set_counts_rejects(params, cfg):
     p = _prompts(cfg)[0]
     rs.submit(Request(rid=0, client_id=0, prompt=p, max_new_tokens=2))
     rs.submit(Request(rid=1, client_id=0, prompt=np.zeros(4, np.int32),
-                      max_new_tokens=CAP + 1))  # can never fit
+                      max_new_tokens=2 * CAP + 1))  # past the pool
     rs.submit(Request(rid=2, client_id=0, prompt=p, max_new_tokens=2))
     while not rs.idle:
         rs.tick()
